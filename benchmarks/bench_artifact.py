@@ -41,6 +41,8 @@ def _timed_analysis(args_list) -> dict:
     analysis/ir-lint CI jobs own the gating."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    # host-side analysis: this process may already hold the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.perf_counter()
     proc = subprocess.run(
         [sys.executable, "-m", "repro.analysis", *args_list],

@@ -15,6 +15,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.common.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -22,6 +24,7 @@ def main() -> None:
                     help="table1|table2|fig3|fig4|kernels|roofline")
     ap.add_argument("--fig4-steps", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows = []
     sections = {}

@@ -21,10 +21,11 @@ per-device collective bytes per kind (trip-count-aware, via
 Regenerate with ``repro-analysis --write-contracts`` and justify the diff
 in review — the file is a budget, not a cache.
 
-NOTE: importing this module sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` (unless a count
-is already set) so the meshes exist — that only works if JAX's backend
-has not initialized yet. Import it only from fresh processes (the
+NOTE: importing this module pins JAX to the CPU platform and appends
+``--xla_force_host_platform_device_count=512`` to ``XLA_FLAGS`` (unless a
+count is already set) so the meshes exist over host devices — a dry run
+never takes an accelerator. That only works if JAX's backend has not
+initialized yet. Import it only from fresh processes (the
 ``repro-analysis`` CLI qualifies); under pytest, monkeypatch
 ``irlint.measure_all`` instead.
 """
@@ -35,6 +36,7 @@ import os
 # must happen before JAX's backend initializes: the targets below need up
 # to 256 fake host devices. An explicit caller-provided count (tests use
 # 8) is respected.
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -153,6 +155,7 @@ def measure_target(t: Target) -> MeasuredTarget:
     from repro.configs import get_config
     from repro.launch.dryrun import dryrun_config, input_specs
     from repro.launch.hlo_cost import parse_hlo_cost
+    from repro.launch.mesh import make_mesh
 
     needed = int(np.prod(t.mesh_shape))
     if jax.device_count() < needed:
@@ -164,7 +167,7 @@ def measure_target(t: Target) -> MeasuredTarget:
             "initializes (the repro-analysis CLI does this)")
 
     cfg = dryrun_config(get_config(t.arch))
-    mesh = jax.make_mesh(t.mesh_shape, ("data", "model"))
+    mesh = make_mesh(t.mesh_shape, ("data", "model"))
     t0 = time.perf_counter()
 
     if t.shape == "weight_sync":

@@ -50,6 +50,7 @@ from repro.core.importance import pack_groups
 from repro.core.rollout import RolloutEngine
 from repro.core.scheduler import AdaptiveConcurrencyController
 from repro.core.weight_sync import ParamStore, make_param_resharder
+from repro.kernels import pallas_by_default
 from repro.models import model as M
 from repro.optim import adam, schedule
 
@@ -57,6 +58,11 @@ FUSED_VOCAB_THRESHOLD = 8192     # above this, use the vocab-blocked logp path
 
 
 def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig, *, use_pallas=False):
+    """``use_pallas`` selects the ``fused_is_grpo`` kernels, which carry
+    their own VJP. The backbone's Pallas kernels and ``fused_logprob`` are
+    forward-only (no VJP), so everything else differentiated here takes
+    the XLA paths: ``chunked_attention``'s custom VJP holds the blocked
+    attention backward."""
     aux_coef = cfg.moe.router_aux_coef if cfg.moe is not None else 0.0
     big_vocab = cfg.vocab_size >= FUSED_VOCAB_THRESHOLD
     if big_vocab and not tcfg.fused_loss and tcfg.entropy_coef > 0.0:
@@ -89,8 +95,7 @@ def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig, *, use_pallas=False):
             # (dry-run HLO finding).
             from repro.kernels.fused_is_grpo import ops as fio_ops
             hidden, aux = M.forward_hidden(
-                params, cfg, inputs, media=media, use_pallas=use_pallas,
-                remat=tcfg.remat)
+                params, cfg, inputs, media=media, remat=tcfg.remat)
             w = M.unembed_weight(params, cfg)
             adv_tok = jnp.broadcast_to(
                 mb["advantages"][:, None], targets.shape)
@@ -111,7 +116,7 @@ def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig, *, use_pallas=False):
             entropy = None
             logp_new, aux = M.score_logprobs(
                 params, cfg, inputs, targets, media=media,
-                use_pallas=use_pallas, remat=tcfg.remat, vocab_block=0)
+                remat=tcfg.remat, vocab_block=0)
             loss, metrics = grpo.grpo_loss(
                 logp_new, behaviour, mb["advantages"], mask,
                 clip_low=tcfg.clip_low, clip_high=tcfg.clip_high,
@@ -120,7 +125,6 @@ def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig, *, use_pallas=False):
                 entropy_coef=tcfg.entropy_coef)
         else:
             logits, aux = M.forward_train(params, cfg, inputs, media=media,
-                                          use_pallas=use_pallas,
                                           remat=tcfg.remat)
             logp_all = jax.nn.log_softmax(logits, axis=-1)
             logp_new = jnp.take_along_axis(
@@ -226,7 +230,7 @@ class CoPRISTrainer:
 
     def __init__(self, model_cfg: ModelConfig, ro_cfg: RolloutConfig,
                  tcfg: TrainConfig, task, *, eos_id: int, key=None,
-                 params=None, use_pallas: bool = False,
+                 params=None, use_pallas: Optional[bool] = None,
                  train_mesh=None, rollout_mesh=None):
         self.cfg = model_cfg
         self.ro = ro_cfg
@@ -240,6 +244,37 @@ class CoPRISTrainer:
         self.key, k_init = jax.random.split(key)
         self.params = params if params is not None else M.init_params(k_init, model_cfg)
         self.opt_state = adam.init(self.params)
+        # disaggregated: train and rollout each get a mesh. With several
+        # devices visible a silent one-device fallback would put both sides
+        # on the first device, so the caller must name the meshes.
+        self.train_mesh = self.rollout_mesh = None
+        if tcfg.disaggregated:
+            if train_mesh is None:
+                if jax.device_count() > 1:
+                    raise ValueError(
+                        f"disaggregated=True with {jax.device_count()} "
+                        "visible devices needs train_mesh and rollout_mesh "
+                        "(launch.mesh.make_disaggregated_meshes)")
+                from repro.launch.mesh import make_cpu_mesh
+                train_mesh = make_cpu_mesh()
+            self.train_mesh = train_mesh
+            self.rollout_mesh = (rollout_mesh if rollout_mesh is not None
+                                 else train_mesh)
+        # each side takes the Pallas kernels when it runs on one TPU device
+        # (kernels.pallas_by_default), unless the caller decides
+        train_pallas, rollout_pallas = (
+            (pallas_by_default(self.train_mesh),
+             pallas_by_default(self.rollout_mesh))
+            if use_pallas is None else (use_pallas, use_pallas))
+        if self.train_mesh is not None and self.train_mesh.devices.size > 1:
+            # the update runs where its inputs live: place params and
+            # optimizer state in the train layout (FSDP x TP)
+            from repro.launch import sharding as shd
+            self.params = jax.device_put(self.params, shd.params_shardings(
+                self.params, self.train_mesh, cfg=model_cfg))
+            self.opt_state = jax.device_put(
+                self.opt_state, shd.opt_state_shardings(
+                    self.params, self.train_mesh, cfg=model_cfg))
         from repro.core.reward_worker import AsyncEnvWorker, AsyncRewardWorker
         timeout = ro_cfg.env_step_timeout or None
         self.reward_worker = AsyncRewardWorker(task.reward, timeout=timeout)
@@ -254,12 +289,12 @@ class CoPRISTrainer:
             env_factory = task.make_env
         self.engine = RolloutEngine(model_cfg, ro_cfg,
                                     self.safe_task.sample_prompt,
-                                    eos_id=eos_id, use_pallas=use_pallas,
+                                    eos_id=eos_id, use_pallas=rollout_pallas,
                                     on_finish=self.reward_worker.submit,
                                     env_factory=env_factory,
                                     env_worker=self.env_worker)
         self._train_step = jax.jit(make_train_step(model_cfg, tcfg,
-                                                   use_pallas=use_pallas))
+                                                   use_pallas=train_pallas))
         self.stage = 0
         self.history = []
         self.last_groups: List = []
@@ -280,11 +315,6 @@ class CoPRISTrainer:
         # flight — older ones are dropped at publish (Laminar drop-stale).
         reshard = None
         if tcfg.disaggregated:
-            from repro.launch.mesh import make_cpu_mesh
-            self.train_mesh = (train_mesh if train_mesh is not None
-                               else make_cpu_mesh())
-            self.rollout_mesh = (rollout_mesh if rollout_mesh is not None
-                                 else self.train_mesh)
             reshard, _ = make_param_resharder(
                 model_cfg, self.params, self.train_mesh, self.rollout_mesh)
         self.param_store = ParamStore(max_versions=self.max_staleness + 1,
@@ -412,6 +442,18 @@ class CoPRISTrainer:
         self.history.append(out)
         return out
 
+    def train_inputs(self, batch: dict, stage: int):
+        """The train step's device batch and learning rate for a packed
+        batch (``pack_groups``) trained at ``stage``."""
+        jb = {k: jnp.asarray(v) for k, v in batch.items()
+              if k in ("tokens", "loss_mask", "behaviour_logp")}
+        jb["advantages"] = grpo.group_advantages(
+            jnp.asarray(batch["rewards"]), self.ro.group_size)
+        lr = schedule.warmup_constant(jnp.asarray(stage, jnp.float32),
+                                      lr=self.tcfg.lr,
+                                      warmup_steps=self.tcfg.warmup_steps)
+        return jb, lr
+
     def _train_on(self, item: _StageBatch, t0: float,
                   t_collected: float) -> dict:
         groups, roll_stats = item.groups, item.roll_stats
@@ -424,14 +466,7 @@ class CoPRISTrainer:
 
         train_stage = self.stage
         batch = pack_groups(groups, max_len=self.engine.max_len)
-        adv = grpo.group_advantages(
-            jnp.asarray(batch["rewards"]), self.ro.group_size)
-        jb = {k: jnp.asarray(v) for k, v in batch.items()
-              if k in ("tokens", "loss_mask", "behaviour_logp")}
-        jb["advantages"] = adv
-        lr = schedule.warmup_constant(jnp.asarray(train_stage, jnp.float32),
-                                      lr=self.tcfg.lr,
-                                      warmup_steps=self.tcfg.warmup_steps)
+        jb, lr = self.train_inputs(batch, train_stage)
         new_params, new_opt, metrics = self._train_step(
             self.params, self.opt_state, jb, lr)
         # publish the update as a new version for the producer (resharded

@@ -44,6 +44,7 @@ from repro.common.config import ModelConfig, RolloutConfig
 from repro.core.buffer import TrajectoryBuffer
 from repro.core.scheduler import ConcurrencyScheduler
 from repro.core.trajectory import Group, Trajectory
+from repro.kernels import pallas_by_default
 from repro.models import model as M
 from repro.sampling import kv_cache as kvc
 from repro.sampling import sampler
@@ -97,7 +98,7 @@ def stop_flags(tok, resp_len_after, total_len_after, *, eos_id: int,
 class RolloutEngine:
     def __init__(self, model_cfg: ModelConfig, ro_cfg: RolloutConfig,
                  prompt_source: Callable[[], Tuple[np.ndarray, object]], *,
-                 eos_id: int, media=None, use_pallas: bool = False,
+                 eos_id: int, media=None, use_pallas: Optional[bool] = None,
                  max_len: Optional[int] = None,
                  on_finish: Optional[Callable] = None,
                  env_factory: Optional[Callable] = None,
@@ -107,6 +108,8 @@ class RolloutEngine:
         self.prompt_source = prompt_source
         self.eos_id = eos_id
         self.media = media
+        if use_pallas is None:
+            use_pallas = pallas_by_default()
         self.use_pallas = use_pallas
 
         self.on_finish = on_finish      # async-reward hook: (traj, answer)

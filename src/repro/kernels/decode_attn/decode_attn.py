@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -35,7 +33,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0)]
     l_start = li * block_l
     lo = cache_len - window if window > 0 else 0
     run = l_start < cache_len
@@ -97,24 +95,28 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, *, window=0,
         _kernel, scale=scale, window=window, attn_softcap=attn_softcap,
         block_l=block_l, num_l_blocks=nl, rep=rep)
 
-    out = pl.pallas_call(
-        kernel,
+    # the per-row lengths ride in SMEM as a scalar-prefetch operand (a
+    # (1,)-blocked SMEM input is not a legal TPU block shape)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * KV, 1, nl),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, _, li: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, rep, hd), lambda b, _, li: (b, 0, 0)),
-            pl.BlockSpec((1, block_l, hd), lambda b, _, li: (b, li, 0)),
-            pl.BlockSpec((1, block_l, hd), lambda b, _, li: (b, li, 0)),
+            pl.BlockSpec((1, rep, hd), lambda b, _, li, ln: (b, 0, 0)),
+            pl.BlockSpec((1, block_l, hd), lambda b, _, li, ln: (b, li, 0)),
+            pl.BlockSpec((1, block_l, hd), lambda b, _, li, ln: (b, li, 0)),
         ],
-        out_specs=pl.BlockSpec((1, rep, hd), lambda b, _, li: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * KV, rep, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, rep, hd), lambda b, _, li, ln: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * KV, rep, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qg, kg, vg)

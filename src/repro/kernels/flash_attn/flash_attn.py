@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -115,7 +113,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, attn_softcap=0.0,
             pltpu.VMEM((block_q, 1), jnp.float32),      # running sum l
             pltpu.VMEM((block_q, hd), jnp.float32),     # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

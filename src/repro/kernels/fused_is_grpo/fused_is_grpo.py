@@ -35,7 +35,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import grpo
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -153,11 +152,27 @@ def _bwd_dw_kernel(t_ref, h_ref, w_ref, lse_ref, eb_ref, a_ref, e_ref,
         dw_ref[...] = acc_scr[...].astype(dw_ref.dtype)
 
 
+# The dw kernel holds a (d, block_v) f32 accumulator beside double-buffered
+# (d, block_v) weight and output blocks: about 20 MiB at d=2048, over the
+# 16 MiB default scoped VMEM (v5e has 128 MiB per core).
+_BWD_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _fit_block_v(V, block_v):
+    """The largest multiple of 128 that is <= ``block_v`` and divides V, so
+    the (d, V) weight is blocked in place rather than copied into a padded
+    buffer (1 GiB at d=2048, V=128256 in f32); ``block_v`` if none does."""
+    for b in range(block_v - block_v % 128, 127, -128):
+        if V % b == 0:
+            return b
+    return block_v
+
+
 def _pad_rows(hidden, w, targets, extras, block_rows, block_v):
     R, d = hidden.shape
     V = w.shape[1]
     block_rows = min(block_rows, max(R, 8))
-    block_v = min(block_v, max(V, 128))
+    block_v = _fit_block_v(V, block_v) if V > block_v else max(V, 128)
     pR = (-R) % block_rows
     pV = (-V) % block_v
     hp = jnp.pad(hidden, ((0, pR), (0, 0)))
@@ -200,7 +215,7 @@ def fused_is_grpo_fwd_rows(hidden, w, targets, behaviour, adv, *,
         out_specs=[row_spec] * 5,
         out_shape=[out_shape] * 5,
         scratch_shapes=[pltpu.VMEM((block_rows, 1), jnp.float32)] * 4,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tp, bp, ap, hp, wp)
@@ -238,8 +253,9 @@ def fused_is_grpo_bwd_rows(hidden, w, targets, lse, ebar, a, e, *,
         out_specs=pl.BlockSpec((block_rows, d), lambda ri, vi: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((hp.shape[0], d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_rows, d), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
     )(tp, hp, wp, lsep, ebp, ap, ep)
 
@@ -259,8 +275,9 @@ def fused_is_grpo_bwd_rows(hidden, w, targets, lse, ebar, a, e, *,
         out_specs=pl.BlockSpec((d, block_v), lambda vi, ri: (0, vi)),
         out_shape=jax.ShapeDtypeStruct((d, wp.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, block_v), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
     )(tp, hp, wp, lsep, ebp, ap, ep)
     return dh[:R].astype(hidden.dtype), dw[:, :V].astype(w.dtype)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -85,7 +83,7 @@ def fused_logprob_rows(hidden, w, targets, *, logit_softcap=0.0,
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tp, hp, wp)

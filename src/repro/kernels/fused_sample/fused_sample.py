@@ -41,8 +41,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 _TINY = np.float32(np.finfo(np.float32).tiny)
 _ROT = ((13, 15, 26, 6), (17, 29, 16, 24))
@@ -67,21 +65,17 @@ def _threefry2x32(k0, k1, x0, x1):
     return x0, x1
 
 
-def _gumbel_bits(gid, k0, k1, *, V, H):
+def _gumbel_bits(gid, k0, k1):
     """Reconstruct jax.random's per-index random bits for a length-V draw.
 
-    jax generates ceil(V/2) counter PAIRS (iota split in half, odd V pads
-    one zero counter) and keeps lane 0 for the first half, lane 1 for the
-    second — the pair partner for index j is computable arithmetically, so
-    any vocab block can regenerate its own bits independently.
+    Under ``jax_threefry_partitionable`` (the default) index j's bits are
+    ``y0 ^ y1`` of ``threefry2x32(key, (0, j))``: the counter is the
+    element's flat index split into (hi, lo) words, so any vocab block
+    regenerates its own bits independently.
     """
-    gid_u = gid.astype(jnp.uint32)
-    lane0 = gid < H
-    x0 = jnp.where(lane0, gid_u, gid_u - _u32(H))
-    x1_l0 = jnp.where(gid + H < V, gid_u + _u32(H), _u32(0))
-    x1 = jnp.where(lane0, x1_l0, gid_u)
-    y0, y1 = _threefry2x32(k0, k1, x0, x1)
-    return jnp.where(lane0, y0, y1)
+    y0, y1 = _threefry2x32(k0, k1, jnp.zeros_like(gid, jnp.uint32),
+                           gid.astype(jnp.uint32))
+    return y0 ^ y1
 
 
 def _gumbel_from_bits(bits):
@@ -112,17 +106,41 @@ def _histogram(byte, weight):
 
 
 def _mass_above(bins):
-    """bins (br, 256) -> per-bin total strictly ABOVE that bin, and total."""
-    incl = jnp.cumsum(bins, axis=1)
-    total = incl[:, -1:]
+    """bins (br, 256) -> per-bin total strictly ABOVE that bin, and total.
+
+    An inclusive prefix sum as a matmul with an upper-triangular ones
+    matrix (Mosaic has no cumsum); HIGHEST precision keeps it f32-exact
+    for the integer counts of the top-k passes."""
+    n = bins.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    upper = (row <= col).astype(jnp.float32)
+    incl = jax.lax.dot(bins, upper, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    total = bins.sum(axis=1, keepdims=True)
     return total - incl, total
+
+
+def _first_true(mask):
+    """(br, n) bool -> (br, 1) int32 index of the first True per row (0 if
+    none): ``jnp.argmax`` on a mask, from reductions Mosaic lowers."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
+    first = jnp.where(mask, idx, mask.shape[1]).min(axis=1, keepdims=True)
+    return jnp.where(first == mask.shape[1], 0, first)
+
+
+def _pick(x, idx):
+    """x (br, n), idx (br, 1) int32 -> x[r, idx[r]] as (br, 1): a masked
+    reduction in place of ``take_along_axis``'s gather."""
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(col == idx, x, 0).sum(axis=1, keepdims=True)
 
 
 def _sample_kernel(k0_ref, k1_ref, l_ref,
                    tok_ref, logp_ref,
                    m_scr, bins_scr, pre_scr, rem_scr, am_scr, c_scr, tau_scr,
                    best_scr, bidx_scr, ltok_scr, sum_scr, *,
-                   schedule, block_v, V, H, temperature, top_k, top_p,
+                   schedule, block_v, V, temperature, top_k, top_p,
                    num_v_blocks):
     ph = pl.program_id(1)
     vi = pl.program_id(2)
@@ -181,16 +199,16 @@ def _sample_kernel(k0_ref, k1_ref, l_ref,
                     # the k-th largest lives in the unique bin whose
                     # strictly-above count is < k_rem <= inclusive count
                     hitb = (above < rem) & (above + bins >= rem)
-                    b = jnp.argmax(hitb, axis=1, keepdims=True)
-                    rem_scr[...] = rem - jnp.take_along_axis(above, b, 1)
+                    b = _first_true(hitb)
+                    rem_scr[...] = rem - _pick(above, b)
                 else:
                     if lvl == 0:
                         c_scr[...] = total * top_p
                     am = am_scr[...]
                     # smallest non-empty bin whose above-mass stays < p*Z
                     ok = (am + above < c_scr[...]) & (bins > 0)
-                    b = jnp.argmax(ok, axis=1, keepdims=True)
-                    am_scr[...] = am + jnp.take_along_axis(above, b, 1)
+                    b = _first_true(ok)
+                    am_scr[...] = am + _pick(above, b)
                 pre = (pre_scr[...] << _u32(8)) | b.astype(jnp.uint32)
                 pre_scr[...] = pre
                 if lvl == 3:
@@ -206,13 +224,13 @@ def _sample_kernel(k0_ref, k1_ref, l_ref,
 
             @pl.when(here)
             def _draw():
-                bits = _gumbel_bits(ids, k0_ref[...], k1_ref[...], V=V, H=H)
+                bits = _gumbel_bits(ids, k0_ref[...], k1_ref[...])
                 g = _gumbel_from_bits(bits)
                 kept = valid & (l >= tau_scr[...])
                 val = jnp.where(kept, l + g, NEG_INF)
                 bmax = val.max(axis=1, keepdims=True)
-                barg = jnp.argmax(val, axis=1, keepdims=True)
-                lsel = jnp.take_along_axis(l, barg, 1)
+                barg = _first_true(val == bmax)
+                lsel = _pick(l, barg)
                 upd = bmax > best_scr[...]
                 best_scr[...] = jnp.where(upd, bmax, best_scr[...])
                 bidx_scr[...] = jnp.where(
@@ -258,7 +276,7 @@ def fused_sample_rows_kernel(keys, logits, *, temperature, top_k, top_p,
 
     kernel = functools.partial(
         _sample_kernel, schedule=tuple(schedule), block_v=block_v, V=V,
-        H=(V + 1) // 2, temperature=float(temperature), top_k=int(top_k),
+        temperature=float(temperature), top_k=int(top_k),
         top_p=float(top_p), num_v_blocks=nv)
     row_spec = pl.BlockSpec((block_rows, 1), lambda ri, ph, vi: (ri, 0))
     scr = lambda shape, dt: pltpu.VMEM(shape, dt)  # noqa: E731
@@ -287,7 +305,7 @@ def fused_sample_rows_kernel(keys, logits, *, temperature, top_k, top_p,
             scr((block_rows, 1), jnp.float32),      # draw: l at argmax
             scr((block_rows, 1), jnp.float32),      # draw: kept sumexp
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(k0, k1, lp)

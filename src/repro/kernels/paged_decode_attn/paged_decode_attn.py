@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -137,7 +135,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_table, cache_len,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, rep, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bt, lens, qg, k_pool, v_pool)

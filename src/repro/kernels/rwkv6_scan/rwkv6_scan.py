@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sf_ref,
             s_scr, *, chunk, num_chunks):
@@ -85,7 +83,7 @@ def wkv6_bh(r, k, v, w, u, s0, *, chunk=128, interpret=True):
             jax.ShapeDtypeStruct(s0.shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rp, kp, vp, wp, u, s0)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, s0_ref,
             y_ref, sf_ref, s_scr, *, chunk, num_chunks):
@@ -90,7 +88,7 @@ def selective_scan_kernel(x, dt, A, Bc, Cc, D, s0, *, block_d=256,
             jax.ShapeDtypeStruct(s0.shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, dtp, A, bp, cp, D2, s0.astype(jnp.float32))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the production mesh and extract roofline terms from the compiled artifact.
 
@@ -13,7 +10,19 @@ Per combination this records: per-device HLO FLOPs / bytes accessed
 (``compiled.memory_analysis()``), and per-device collective bytes parsed
 from the partitioned HLO (all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute operand sizes).
+
+Importing this module pins JAX to the CPU platform and appends 512 host
+devices to ``XLA_FLAGS`` (unless a count is already set): the dry run
+lowers over host devices and never takes an accelerator.
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+if "--xla_force_host_platform_device_count" not in os.environ.get(
+        "XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count"
+                                 "=512").strip()
 
 import argparse
 import dataclasses
@@ -285,8 +294,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     # the scan-over-layers programs need the trip-count-aware HLO walker.
     # We record both: raw XLA numbers as a cross-check, walker as primary.
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # older jaxlib: one dict per device
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     walked = parse_hlo_cost(hlo_text)
     flops = float(walked["flops"])

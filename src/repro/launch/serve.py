@@ -24,6 +24,7 @@ from typing import List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import ModelConfig, RolloutConfig
 from repro.configs import get_config, get_smoke_config
 from repro.core.rollout import RolloutEngine
@@ -214,6 +215,7 @@ def main(argv=None):
     ap.add_argument("--kv-num-pages", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     serve, cfg = make_serve_engine(
         args.arch, smoke=args.smoke, max_prompt_len=args.prompt_len,

@@ -1,5 +1,5 @@
-"""RL training launcher (live hardware — CPU-scale here, same code path on
-a real cluster once params/opt are sharded with launch/sharding rules).
+"""RL training launcher: the CoPRIS trainer on the visible devices (the
+Pallas kernel path on one TPU device, the XLA path elsewhere).
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch tiny --mode copris --steps 200 --concurrency 16 \
@@ -17,12 +17,14 @@ import jax
 import numpy as np
 
 from repro.checkpoint import ckpt
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import RolloutConfig, TrainConfig
 from repro.configs import get_config, get_smoke_config
 from repro.core.copris import CoPRISTrainer
 from repro.data.sft import sft_warmup
 from repro.data.tasks import (AdditionTask, EOS, MultiTurnMathTask,
                               ToolCallTask)
+from repro.launch.mesh import make_disaggregated_meshes
 from repro.models import model as M
 
 
@@ -38,7 +40,7 @@ def make_task(name: str, seed: int):
     raise ValueError(f"unknown task {name!r}")
 
 
-def main(argv=None):
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny")
     ap.add_argument("--smoke", action="store_true",
@@ -73,8 +75,9 @@ def main(argv=None):
     ap.add_argument("--disaggregated", action="store_true",
                     help="route every published params version through the "
                          "versioned ParamStore reshard (train FSDP layout "
-                         "-> rollout serve_tp_only layout); requires "
-                         "--overlap")
+                         "-> rollout serve_tp_only layout); with several "
+                         "devices visible, half train and half roll out; "
+                         "requires --overlap")
     ap.add_argument("--adaptive-concurrency", action="store_true",
                     help="overlap-aware N' controller: adjust the in-flight "
                          "rollout target between stages from observed "
@@ -90,8 +93,34 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", default=None)
     ap.add_argument("--eval-every", type=int, default=25)
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build_trainer(args, cfg, task, *, params=None) -> CoPRISTrainer:
+    """The trainer ``main`` runs, from its parsed arguments: rollout and
+    train configs, the disaggregated meshes over the visible devices, and
+    the platform's kernel path (``CoPRISTrainer``'s default)."""
+    ro = RolloutConfig(batch_size=args.batch_size, group_size=args.group_size,
+                       max_prompt_len=16, max_response_len=args.max_response,
+                       concurrency=args.concurrency, mode=args.mode,
+                       adaptive_concurrency=args.adaptive_concurrency,
+                       concurrency_min=args.concurrency_min,
+                       concurrency_max=args.concurrency_max,
+                       env_step_timeout=args.env_timeout)
+    tc = TrainConfig(lr=args.lr, warmup_steps=5, total_steps=args.steps,
+                     use_is_correction=not args.no_is, seed=args.seed,
+                     overlap=args.overlap, max_staleness=args.max_staleness,
+                     disaggregated=args.disaggregated)
+    train_mesh = rollout_mesh = None
+    if args.disaggregated and jax.device_count() > 1:
+        train_mesh, rollout_mesh = make_disaggregated_meshes()
+    return CoPRISTrainer(cfg, ro, tc, task, eos_id=EOS, params=params,
+                         train_mesh=train_mesh, rollout_mesh=rollout_mesh)
+
+
+def main(argv=None):
+    args = make_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     task = make_task(args.task, args.seed)
     os.makedirs(args.out, exist_ok=True)
@@ -113,18 +142,7 @@ def main(argv=None):
                                   steps=args.sft_warmup, log_every=50)
         print(f"  warmup done (loss {loss:.3f})")
 
-    ro = RolloutConfig(batch_size=args.batch_size, group_size=args.group_size,
-                       max_prompt_len=16, max_response_len=args.max_response,
-                       concurrency=args.concurrency, mode=args.mode,
-                       adaptive_concurrency=args.adaptive_concurrency,
-                       concurrency_min=args.concurrency_min,
-                       concurrency_max=args.concurrency_max,
-                       env_step_timeout=args.env_timeout)
-    tc = TrainConfig(lr=args.lr, warmup_steps=5, total_steps=args.steps,
-                     use_is_correction=not args.no_is, seed=args.seed,
-                     overlap=args.overlap, max_staleness=args.max_staleness,
-                     disaggregated=args.disaggregated)
-    tr = CoPRISTrainer(cfg, ro, tc, task, eos_id=EOS, params=params)
+    tr = build_trainer(args, cfg, task, params=params)
     if args.resume:
         # restore republishes through the ParamStore so the rollout side
         # acquires the checkpointed weights, not the construction version

@@ -430,8 +430,13 @@ def attention_block(params, cfg, x, positions, *, kind: str,
                 c, t, (i, 0, 0)))(k_cache, k.astype(k_cache.dtype), idx)
             v_cache = jax.vmap(lambda c, t, i: jax.lax.dynamic_update_slice(
                 c, t, (i, 0, 0)))(v_cache, v.astype(v_cache.dtype), idx)
-        out = decode_attention(q, k_cache, v_cache, cache_len + 1,
-                               window=window, attn_softcap=cap)
+        if use_pallas:
+            from repro.kernels.decode_attn import ops as da_ops
+            out = da_ops.decode_attention(q, k_cache, v_cache, cache_len + 1,
+                                          window=window, attn_softcap=cap)
+        else:
+            out = decode_attention(q, k_cache, v_cache, cache_len + 1,
+                                   window=window, attn_softcap=cap)
         new_kv = (k_cache, v_cache)
 
     y = out.reshape(*out.shape[:-2], h * hd) @ params["wo"].astype(dt)

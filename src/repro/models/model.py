@@ -122,10 +122,10 @@ def backbone(params, cfg: ModelConfig, tokens, *, positions=None, media=None,
 
 
 def forward_train(params, cfg: ModelConfig, tokens, *, media=None,
-                  seq_mask=None, use_pallas=False, remat=True):
+                  seq_mask=None, remat=True):
     """Full logits (B, S, V) fp32 + aux dict."""
     x, _, aux = backbone(params, cfg, tokens, media=media, seq_mask=seq_mask,
-                         mode="train", use_pallas=use_pallas, remat=remat)
+                         mode="train", remat=remat)
     return _logits(params, cfg, x), {"router_aux": aux}
 
 
@@ -135,14 +135,14 @@ def unembed_weight(params, cfg: ModelConfig):
 
 
 def forward_hidden(params, cfg: ModelConfig, tokens, *, media=None,
-                   seq_mask=None, use_pallas=False, remat=True):
+                   seq_mask=None, remat=True):
     """Backbone only: final-norm hidden states (B, S, d) + aux dict.
 
     The pre-unembedding entry point for fused losses (kernels/fused_is_grpo)
     that consume (hidden, unembed_weight) directly and never materialise
     the (B, S, V) logits."""
     x, _, aux = backbone(params, cfg, tokens, media=media, seq_mask=seq_mask,
-                         mode="train", use_pallas=use_pallas, remat=remat)
+                         mode="train", remat=remat)
     return x, {"router_aux": aux}
 
 
@@ -154,22 +154,17 @@ def token_logprobs_from_logits(logits, targets):
 
 
 def score_logprobs(params, cfg: ModelConfig, tokens, targets, *, media=None,
-                   seq_mask=None, use_pallas=False, remat=True,
-                   vocab_block: int = 0):
+                   seq_mask=None, remat=True, vocab_block: int = 0):
     """Per-token log-prob of ``targets`` given ``tokens`` (same length,
     targets[t] is the next-token label for position t). Memory-safe for huge
     vocabularies via the fused vocab-blocked gather-logsumexp path.
     Returns (logps (B, S) fp32, aux)."""
     x, _, aux = backbone(params, cfg, tokens, media=media, seq_mask=seq_mask,
-                         mode="train", use_pallas=use_pallas, remat=remat)
-    w = unembed_weight(params, cfg)
-    if use_pallas:
-        from repro.kernels.fused_logprob import ops as flp_ops
-        lp = flp_ops.fused_logprob(x, w, targets, logit_softcap=cfg.logit_softcap)
-    else:
-        from repro.kernels.fused_logprob import ref as flp_ref
-        lp = flp_ref.fused_logprob(x, w, targets, logit_softcap=cfg.logit_softcap,
-                                   vocab_block=vocab_block)
+                         mode="train", remat=remat)
+    from repro.kernels.fused_logprob import ref as flp_ref
+    lp = flp_ref.fused_logprob(x, unembed_weight(params, cfg), targets,
+                               logit_softcap=cfg.logit_softcap,
+                               vocab_block=vocab_block)
     return lp, {"router_aux": aux}
 
 

@@ -19,7 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def apply_moe_shardmap(params, cfg, x, mesh, *, capacity_factor=None):
@@ -105,12 +104,12 @@ def apply_moe_shardmap(params, cfg, x, mesh, *, capacity_factor=None):
     if pT:
         xt_all = jnp.pad(xt_all, ((0, pT), (0, 0)))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_nosh, mesh=mesh,
         in_specs=(P(grid, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(grid, None), P(dp or None)),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(xt_all, params["router"], params["wi"], params["wg"],
                 params["wo"])
     y = y[:T_all].reshape(B, S, d)

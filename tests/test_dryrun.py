@@ -14,7 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(code):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
                           capture_output=True, text=True, timeout=900)
 
@@ -92,10 +92,7 @@ def test_sharding_rules_all_archs():
     from repro.launch import sharding as shd
     from repro.models import model as M
 
-    try:
-        mesh = AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:   # older jax: AbstractMesh(((name, size), ...))
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch in ASSIGNED_ARCHS:
         cfg = get_config(arch)
         shape = jax.eval_shape(lambda k: M.init_params(k, cfg),

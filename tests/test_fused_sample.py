@@ -30,7 +30,7 @@ def _logits(key, B, V, scale=4.0):
     return jax.random.normal(jax.random.PRNGKey(key), (B, V)) * scale
 
 
-@pytest.mark.parametrize("V", [7, 100, 2049])   # odd V: counter half-split pad
+@pytest.mark.parametrize("V", [7, 100, 2049])   # odd V: padded last block
 @pytest.mark.parametrize("cfg", CONFIGS)
 def test_token_bit_identity(V, cfg):
     temperature, top_p, top_k = cfg

@@ -44,8 +44,11 @@ def test_snapshot_roundtrip_equals_reprefill():
 
 def test_engine_kv_snapshot_mode():
     task = AdditionTask(max_value=20, seed=11)
+    # eviction by construction: twice the slots B*G needs, and a length cap
+    # high enough that random EOS ends responses at different steps — the
+    # stage closes on its B-th group while other slots are mid-response
     ro = RolloutConfig(batch_size=3, group_size=2, max_prompt_len=16,
-                       max_response_len=32, concurrency=4, mode="copris",
+                       max_response_len=128, concurrency=12, mode="copris",
                        resume_strategy="kv_snapshot")
     params = M.init_params(jax.random.PRNGKey(2), CFG)
     eng = RolloutEngine(CFG, ro, task.sample_prompt, eos_id=EOS)

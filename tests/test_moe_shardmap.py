@@ -14,12 +14,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, dataclasses
 from repro.configs import get_smoke_config
 from repro.models import moe as moe_mod
+from repro.launch.mesh import make_mesh
 from repro.models.moe_shardmap import apply_moe_shardmap
 
 cfg = get_smoke_config("deepseek-moe-16b")
 cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
     cfg.moe, num_experts=8, top_k=2))
-mesh = jax.make_mesh((2, 4), ("data", "model"))   # ep=4, 2 experts/device
+mesh = make_mesh((2, 4), ("data", "model"))   # ep=4, 2 experts/device
 key = jax.random.PRNGKey(0)
 p = moe_mod.init_moe(key, cfg, jnp.float32)
 for B, S in ((4, 8), (2, 13)):       # 13: exercises the sequence padding
@@ -45,7 +46,7 @@ print("OK")
 def test_shardmap_dispatch_matches_dense():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", CODE], env=env, cwd=ROOT,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]

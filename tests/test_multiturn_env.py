@@ -341,8 +341,12 @@ def test_engine_multiturn_collect():
                 assert t.turn_starts[1] > 0
                 assert 0 in t.roles
     assert multi > 0, "expected at least one multi-turn episode"
+    # env_turns counts every observation integrated this (first) stage,
+    # including those of episodes evicted to the buffer at its end
     assert stats["env_turns"] == sum(
-        t.num_turns - 1 for g in groups for t in g.trajectories)
+        t.num_turns - 1
+        for g in list(groups) + list(eng.buffer.groups())
+        for t in g.trajectories)
 
 
 def test_engine_multiturn_behaviour_logps_match_policy():

@@ -219,6 +219,26 @@ def test_paged_decode_pallas_wiring():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
+def test_dense_decode_pallas_wiring():
+    """use_pallas=True routes the dense-cache decode through the Pallas
+    ``decode_attn`` kernel (interpret mode on CPU): logits and the written
+    cache (later layers' K/V follow earlier layers' attention) must match
+    the XLA decode path."""
+    B, P, MAXLEN = 2, 8, 32
+    toks = jax.random.randint(jax.random.PRNGKey(6), (B, P), 0,
+                              CFG.vocab_size)
+    lengths = jnp.array([P, P - 3])
+    _, cache = M.prefill(PARAMS, CFG, toks, lengths,
+                         M.init_cache(CFG, B, MAXLEN))
+    tok = jnp.array([3, 7])
+    ref, ref_cache = M.decode_step(PARAMS, CFG, tok, cache, lengths)
+    out, out_cache = M.decode_step(PARAMS, CFG, tok, cache, lengths,
+                                   use_pallas=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+    for a, b in zip(jax.tree.leaves(out_cache), jax.tree.leaves(ref_cache)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
+
+
 def test_paged_snapshot_roundtrip():
     """extract_snapshot returns a page-list blob (never densified) that
     insert_snapshot restores bit-identically into a fresh pool."""
@@ -402,8 +422,11 @@ def test_paged_kv_snapshot_resume():
     page-list blobs (dict, never a dense slice) and later stages restore
     them."""
     task = AdditionTask(max_value=20, seed=11)
+    # eviction by construction: twice the slots B*G needs, and a length cap
+    # high enough that random EOS ends responses at different steps — the
+    # stage closes on its B-th group while other slots are mid-response
     ro = RolloutConfig(batch_size=3, group_size=2, max_prompt_len=16,
-                       max_response_len=32, concurrency=4, mode="copris",
+                       max_response_len=128, concurrency=12, mode="copris",
                        resume_strategy="kv_snapshot", kv_backend="paged",
                        kv_page_size=16)
     eng = RolloutEngine(CFG, ro, task.sample_prompt, eos_id=EOS)
