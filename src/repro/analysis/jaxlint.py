@@ -599,6 +599,10 @@ class AsyncDispatchTiming(Rule):
     ``block_until_ready``/``device_get``/``.item()`` between the dispatch
     and the closing stamp.
 
+    A phase span (``with Span(...)``, ``repro.common.spans``) reads the
+    clock when its block is entered and left, so its block is an interval
+    too: a dispatch inside it must be followed, inside it, by a sync.
+
     Fix: ``jax.block_until_ready(result)`` (for the engine: its cache)
     before reading the end-of-interval clock.
     """
@@ -609,6 +613,8 @@ class AsyncDispatchTiming(Rule):
 
     CLOCKS = {"time.perf_counter", "time.time", "time.monotonic",
               "perf_counter", "monotonic"}
+    # context managers that time their block (last dotted component)
+    SPANS = {"Span"}
 
     def check(self, ctx: ModuleCtx) -> List[Finding]:
         findings: List[Finding] = []
@@ -619,7 +625,44 @@ class AsyncDispatchTiming(Rule):
             scopes.append(f.body)
         for body in scopes:
             self._check_scope(ctx, body, jit_names, findings)
+            self._check_spans(ctx, body, jit_names, findings)
         return findings
+
+    def _call_kind(self, node, jit_names) -> Optional[str]:
+        """The call's kind: "sync", "dispatch" or None."""
+        name = call_name(node) or ""
+        last = name.split(".")[-1]
+        func = node.func
+        if (isinstance(func, ast.Attribute) and func.attr in SYNCING_CALLS) \
+                or last in SYNCING_CALLS:
+            return "sync"
+        if name in jit_names or last in DISPATCHING_CALLS:
+            return "dispatch"
+        return None
+
+    def _check_spans(self, ctx, body, jit_names, findings):
+        for stmt in walk_stmts_in_order(body):
+            if not isinstance(stmt, (ast.With, ast.AsyncWith)) or not any(
+                    isinstance(it.context_expr, ast.Call)
+                    and (call_name(it.context_expr) or "").split(".")[-1]
+                    in self.SPANS for it in stmt.items):
+                continue
+            events = []
+            for inner in stmt.body:
+                for node in ast.walk(inner):
+                    if isinstance(node, ast.Call):
+                        kind = self._call_kind(node, jit_names)
+                        if kind is not None:
+                            events.append((node.lineno, kind, call_name(node)))
+            pending = None
+            for _, kind, name in sorted(events):
+                pending = name if kind == "dispatch" else None
+            if pending is not None:
+                findings.append(ctx.finding(
+                    self, stmt,
+                    f"span closes over async dispatch {pending}() with no "
+                    "block_until_ready/device_get after it — times the "
+                    "enqueue, not the compute"))
 
     def _is_clock_call(self, node) -> bool:
         return isinstance(node, ast.Call) and call_name(node) in self.CLOCKS
@@ -653,19 +696,12 @@ class AsyncDispatchTiming(Rule):
                     continue
             for node in ast.walk(stmt):
                 if isinstance(node, ast.Call):
-                    name = call_name(node) or ""
-                    last = name.split(".")[-1]
-                    func = node.func
-                    if isinstance(func, ast.Attribute) and \
-                            func.attr in SYNCING_CALLS:
+                    kind = self._call_kind(node, jit_names)
+                    if kind == "sync":
                         events.append((node.lineno, "sync", None))
-                    elif last in SYNCING_CALLS:
-                        events.append((node.lineno, "sync", None))
-                    elif name in jit_names or \
-                            (name.startswith("self.")
-                             and name in jit_names) or \
-                            last in DISPATCHING_CALLS:
-                        events.append((node.lineno, "dispatch", name))
+                    elif kind == "dispatch":
+                        events.append((node.lineno, "dispatch",
+                                       call_name(node) or ""))
                 if isinstance(node, ast.BinOp) and \
                         isinstance(node.op, ast.Sub):
                     hi = self._stamp_line(node.left, stamps, node.lineno)

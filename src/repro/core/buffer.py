@@ -44,11 +44,6 @@ class TrajectoryBuffer:
         return sum(1 for g in self._groups.values()
                    for t in g.trajectories if not t.done)
 
-    @property
-    def num_finished_waiting(self) -> int:
-        return sum(1 for g in self._groups.values()
-                   for t in g.trajectories if t.done)
-
     # ------------------------------------------------------------------
     def pop_resumable(self, exclude=()) -> Optional[Trajectory]:
         """Longest unfinished partial trajectory (prioritized resumption).

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.config import ModelConfig, RolloutConfig, TrainConfig
+from repro.common.spans import Span
 from repro.core import grpo
 from repro.core.importance import pack_groups
 from repro.core.rollout import RolloutEngine
@@ -425,18 +426,20 @@ class CoPRISTrainer:
         to ``max_staleness`` updates behind the ones being trained)."""
         t0 = time.perf_counter()
         if self.overlap:
-            self._ensure_producer()
-            item = self._next_batch()
+            with Span("trainer.batch_wait"):
+                self._ensure_producer()
+                item = self._next_batch()
         else:
             # same handoff as the producer thread: freshest published
             # version — identical to (self.params, self.stage) here, since
             # the sequential consumer is the only publisher
-            params, version = self.param_store.acquire()
-            with self._progress:
-                idx = self._collect_idx
-            item = self._collect_stage(params, version, idx)
-            with self._progress:
-                self._collect_idx += 1
+            with Span("trainer.collect"):
+                params, version = self.param_store.acquire()
+                with self._progress:
+                    idx = self._collect_idx
+                item = self._collect_stage(params, version, idx)
+                with self._progress:
+                    self._collect_idx += 1
         t_collected = time.perf_counter()
         out = self._train_on(item, t0, t_collected)
         self.history.append(out)
@@ -461,32 +464,39 @@ class CoPRISTrainer:
         # async rewards on both arms); gather resolves any stragglers and
         # runs on the CONSUMER thread, so the producer keeps submitting
         # stage k+1 rewards while stage k gathers
-        self.reward_worker.gather(groups)
+        with Span("trainer.reward"):
+            self.reward_worker.gather(groups)
         t_reward = time.perf_counter()
 
+        phases = {}
         train_stage = self.stage
-        batch = pack_groups(groups, max_len=self.engine.max_len)
-        jb, lr = self.train_inputs(batch, train_stage)
-        new_params, new_opt, metrics = self._train_step(
-            self.params, self.opt_state, jb, lr)
-        # publish the update as a new version for the producer (resharded
-        # to the rollout layout when disaggregated), then wake its
-        # staleness gate. Only the consumer thread mutates
-        # params/opt_state/stage; the producer reads exclusively through
-        # the store, so no lock is needed around the plain assignments.
-        self.params, self.opt_state = new_params, new_opt
-        self.stage = train_stage + 1
-        self.param_store.publish(new_params, self.stage)
-        with self._progress:
-            self._trained_batches += 1
-            self._progress.notify_all()
-        # jit dispatch is async: without forcing completion here, t_end
-        # excludes the train compute (and, overlapped, its contention with
-        # the producer's rollout on a shared device) — step_time/update_time
-        # would under-report and overlap_saved_time overstate. Publish
-        # happens BEFORE the block so the producer's gate opens on the
-        # future-backed params as early as possible.
-        jax.block_until_ready((new_params, metrics))
+        with Span("trainer.pack", phases, "pack_time"):
+            batch = pack_groups(groups, max_len=self.engine.max_len)
+            jb, lr = self.train_inputs(batch, train_stage)
+            # the span covers the host->device copy, not its enqueue
+            jax.block_until_ready((jb, lr))
+        with Span("trainer.train", phases, "train_time"):
+            new_params, new_opt, metrics = self._train_step(
+                self.params, self.opt_state, jb, lr)
+            # publish the update as a new version for the producer
+            # (resharded to the rollout layout when disaggregated), then
+            # wake its staleness gate. Only the consumer thread mutates
+            # params/opt_state/stage; the producer reads exclusively through
+            # the store, so no lock is needed around the plain assignments.
+            self.params, self.opt_state = new_params, new_opt
+            self.stage = train_stage + 1
+            self.param_store.publish(new_params, self.stage)
+            with self._progress:
+                self._trained_batches += 1
+                self._progress.notify_all()
+            # jit dispatch is async: without forcing completion here, t_end
+            # excludes the train compute (and, overlapped, its contention
+            # with the producer's rollout on a shared device) —
+            # step_time/update_time would under-report and
+            # overlap_saved_time overstate. Publish happens BEFORE the block
+            # so the producer's gate opens on the future-backed params as
+            # early as possible.
+            jax.block_until_ready((new_params, metrics))
         t_end = time.perf_counter()
 
         # staleness accounting relative to the CONSUMING training stage:
@@ -526,6 +536,12 @@ class CoPRISTrainer:
             reward_mean=float(batch["rewards"].mean()),
             reward_std=float(batch["rewards"].std()),
             rollout_time=rollout_time,
+            # the rollout's phases (engine spans) and the update's
+            prefill_time=roll_stats["prefill_time"],
+            decode_time=roll_stats["decode_time"],
+            replay_time=roll_stats["replay_time"],
+            pack_time=phases["pack_time"],
+            train_time=phases["train_time"],
             # the reward worker's own gather timing: time the trainer spent
             # blocked on reward resolution (subtracting rollout wall-time
             # from a different clock span could go negative)

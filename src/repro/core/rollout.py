@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.config import ModelConfig, RolloutConfig
+from repro.common.spans import Span
 from repro.core.buffer import TrajectoryBuffer
 from repro.core.scheduler import ConcurrencyScheduler
 from repro.core.trajectory import Group, Trajectory
@@ -504,6 +505,10 @@ class RolloutEngine:
         row_map, primary = [], []
         for (i, traj), f, L in zip(pending, fulls, lens):
             fresh = traj.response_len == 0
+            if not fresh:
+                # a partial resumed by re-prefill: its prompt and every
+                # response token so far go through the model again
+                self._stats["reprefill_tokens"] += L
             if share and fresh and traj.group_id in row_of_gid:
                 row_map.append(row_of_gid[traj.group_id])
                 primary.append(False)
@@ -549,7 +554,6 @@ class RolloutEngine:
             jnp.asarray(gid), jnp.asarray(sidx), jnp.asarray(resp_idx),
             stage_key)
         tok, logp = jax.device_get((tok, logp))
-        self._stats["prefill_calls"] += 1
         self._stats["prefill_rows"] += len(rows)
         self._stats["host_syncs"] += 1
         finished = []
@@ -576,18 +580,21 @@ class RolloutEngine:
         engine prefills at dispatch time, so these become 1-token partials
         that eviction buffers for prioritized resumption (rather than
         silently un-dispatching them)."""
-        while pending:
-            finished = self._prefill_pending(pending, params, stage_key)
-            freed = []
-            for i, traj, reason in finished:
-                self._stop_slot(traj, reason, sched)
-                self.slots[i] = None
-                self.backend.free_slot(i)
-                freed.append(i)
-            pending = []
-            if freed:
-                sched.harvest()
-                pending = self._dispatch_refills(freed, sched)
+        if not pending:
+            return
+        with Span("engine.prefill", self._stats, "prefill_time"):
+            while pending:
+                finished = self._prefill_pending(pending, params, stage_key)
+                freed = []
+                for i, traj, reason in finished:
+                    self._stop_slot(traj, reason, sched)
+                    self.slots[i] = None
+                    self.backend.free_slot(i)
+                    freed.append(i)
+                pending = []
+                if freed:
+                    sched.harvest()
+                    pending = self._dispatch_refills(freed, sched)
 
     def _preempt_slot(self, i: int, sched: ConcurrencyScheduler,
                       copies: Optional[List[Tuple[int, int]]] = None):
@@ -701,14 +708,16 @@ class RolloutEngine:
                 f"[1, pool={self.pool}] — the slot pool is sized to "
                 "concurrency_max at engine construction")
         self._stage = stage_id
-        self._stats = dict(prefill_count=0, prefill_tokens=0, prefill_calls=0,
-                           prefill_rows=0, shared_prefill_rows=0,
+        self._stats = dict(prefill_count=0, prefill_tokens=0,
+                           reprefill_tokens=0, prefill_rows=0,
+                           shared_prefill_rows=0,
                            decode_steps=0, decode_chunks=0, host_syncs=0,
                            active_slot_steps=0, slot_steps=0, generated=0,
                            overgen_tokens=0, resumed=0, evicted=0,
                            admission_blocked=0, page_preemptions=0,
                            env_steps=0, env_turns=0, env_failures=0,
-                           env_wait_time=0.0)
+                           env_wait_time=0.0, prefill_time=0.0,
+                           decode_time=0.0, replay_time=0.0)
         self._reserved_pages = 0
         self._reservations.clear()
         self._t0 = time.perf_counter()
@@ -766,13 +775,14 @@ class RolloutEngine:
         D = self._chunk
         resp_len = np.array([0 if t is None else t.response_len
                              for t in self.slots], np.int32)
-        self.cache, ys = self._decode_chunk_fn(
-            params, self.cache, jnp.asarray(self.last_token),
-            jnp.asarray(self.cache_len), jnp.asarray(live),
-            jnp.asarray(resp_len), jnp.asarray(self.slot_gid),
-            jnp.asarray(self.slot_sidx), key,
-            self.backend.block_table_device())
-        toks, logps, was_active = jax.device_get(ys)   # ONE transfer
+        with Span("engine.decode", self._stats, "decode_time"):
+            self.cache, ys = self._decode_chunk_fn(
+                params, self.cache, jnp.asarray(self.last_token),
+                jnp.asarray(self.cache_len), jnp.asarray(live),
+                jnp.asarray(resp_len), jnp.asarray(self.slot_gid),
+                jnp.asarray(self.slot_sidx), key,
+                self.backend.block_table_device())
+            toks, logps, was_active = jax.device_get(ys)   # ONE transfer
         self._stats["decode_chunks"] += 1
         self._stats["host_syncs"] += 1
         self._stats["decode_steps"] += D
@@ -780,33 +790,34 @@ class RolloutEngine:
 
         # host replay of the chunk, in (step, slot) order
         pending = []
-        for d in range(D):
-            if sched.done or not live.any():
-                self._stats["overgen_tokens"] += int(was_active[d:].sum())
-                break
-            assert np.array_equal(was_active[d], live), \
-                "device/host stop detection desynchronised"
-            step_live = np.nonzero(live)[0]
-            self._stats["active_slot_steps"] += len(step_live)
-            freed = []
-            for i in step_live:
-                i = int(i)
-                traj = self.slots[i]
-                self.cache_len[i] += 1
-                tok = int(toks[d, i])
-                traj.append(tok, float(logps[d, i]), stage_id)
-                self.last_token[i] = tok
-                self._stats["generated"] += 1
-                reason = self._maybe_done(traj)
-                if reason:
-                    self._stop_slot(traj, reason, sched)
-                    self.slots[i] = None
-                    self.backend.free_slot(i)
-                    live[i] = False
-                    freed.append(i)
-            if freed:
-                sched.harvest()
-                pending.extend(self._dispatch_refills(freed, sched))
+        with Span("engine.replay", self._stats, "replay_time"):
+            for d in range(D):
+                if sched.done or not live.any():
+                    self._stats["overgen_tokens"] += int(was_active[d:].sum())
+                    break
+                assert np.array_equal(was_active[d], live), \
+                    "device/host stop detection desynchronised"
+                step_live = np.nonzero(live)[0]
+                self._stats["active_slot_steps"] += len(step_live)
+                freed = []
+                for i in step_live:
+                    i = int(i)
+                    traj = self.slots[i]
+                    self.cache_len[i] += 1
+                    tok = int(toks[d, i])
+                    traj.append(tok, float(logps[d, i]), stage_id)
+                    self.last_token[i] = tok
+                    self._stats["generated"] += 1
+                    reason = self._maybe_done(traj)
+                    if reason:
+                        self._stop_slot(traj, reason, sched)
+                        self.slots[i] = None
+                        self.backend.free_slot(i)
+                        live[i] = False
+                        freed.append(i)
+                if freed:
+                    sched.harvest()
+                    pending.extend(self._dispatch_refills(freed, sched))
         self._prefill_rounds(pending, sched, params, key)
         return True
 
@@ -822,32 +833,32 @@ class RolloutEngine:
         sched = self._sched
         stage_id = self._stage
         t0 = self._t0
-        # early termination: evict in-flight work back to the buffer
-        for i, traj in enumerate(self.slots):
-            if traj is not None:
-                if self.ro.resume_strategy == "kv_snapshot":
-                    traj.kv_snapshot = self.backend.extract_snapshot(i)
-                    traj.snap_cache_len = int(self.cache_len[i])
-                    traj.snap_last_token = int(self.last_token[i])
-                sched.release(traj)
-                self.slots[i] = None
-                self.backend.free_slot(i)
-                self._stats["evicted"] += 1
-        sched.harvest()
-
-        groups = sched.completed[: self.ro.batch_size]
-        # surplus complete groups stay buffered for the next step
-        for g in sched.completed[self.ro.batch_size:]:
-            self.buffer.add_group(g)
-
         st = self._stats
-        # the last decode chunk's cache update may still be dispatching —
-        # force completion so wall_time covers compute, not enqueueing
-        jax.block_until_ready(self.cache)
+        with Span("engine.end_stage"):
+            # early termination: evict in-flight work back to the buffer
+            for i, traj in enumerate(self.slots):
+                if traj is not None:
+                    if self.ro.resume_strategy == "kv_snapshot":
+                        traj.kv_snapshot = self.backend.extract_snapshot(i)
+                        traj.snap_cache_len = int(self.cache_len[i])
+                        traj.snap_last_token = int(self.last_token[i])
+                    sched.release(traj)
+                    self.slots[i] = None
+                    self.backend.free_slot(i)
+                    self._stats["evicted"] += 1
+            sched.harvest()
+
+            groups = sched.completed[: self.ro.batch_size]
+            # surplus complete groups stay buffered for the next step
+            for g in sched.completed[self.ro.batch_size:]:
+                self.buffer.add_group(g)
+
+            # the last decode chunk's cache update may still be dispatching —
+            # force completion so wall_time covers compute, not enqueueing
+            jax.block_until_ready(self.cache)
         st["wall_time"] = time.perf_counter() - t0
         st["concurrency_target"] = sched.target_concurrency
         st["buffer_unfinished"] = self.buffer.num_unfinished
-        st["buffer_waiting"] = self.buffer.num_finished_waiting
         # how stale the carried-over buffer already is for the NEXT stage —
         # the overlapped pipeline's leading indicator of IS-correction load
         st["buffer_off_policy_frac"] = \
@@ -855,7 +866,6 @@ class RolloutEngine:
         st["utilization"] = (st["active_slot_steps"] / st["slot_steps"]
                              if st["slot_steps"] else 1.0)
         st["tokens_per_sync"] = st["generated"] / max(1, st["host_syncs"])
-        n_traj = sum(len(g.trajectories) for g in groups)
         # off-policy accounting relative to THIS collect's stage (the stage
         # about to consume the batch), plus a per-stage-gap histogram —
         # {gap: token count} where gap = stage_id - token's stage. Under the
@@ -870,7 +880,6 @@ class RolloutEngine:
         st["off_policy_tokens"] = int(counts[gaps > 0].sum())
         st["multi_stage_trajs"] = sum(1 for g in groups for t in g.trajectories
                                       if t.num_stages > 1)
-        st["batch_trajs"] = n_traj
         with self._stats_lock:
             for k_, v in st.items():
                 if isinstance(v, (int, float)):

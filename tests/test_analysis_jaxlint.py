@@ -229,3 +229,37 @@ def test_jax104_good_interval_without_dispatch():
         return time.perf_counter() - t0, total
     """
     assert findings(src, "JAX104") == []
+
+
+def test_jax104_bad_span_closes_before_sync():
+    src = """
+    import jax
+    from repro.common.spans import Span
+
+    f = jax.jit(lambda x: x * 2)
+
+    def step(x, stats):
+        with Span("engine.decode", stats, "decode_time"):
+            y = f(x)
+        return jax.device_get(y)
+    """
+    fs = findings(src, "JAX104")
+    assert len(fs) == 1 and "span closes" in fs[0].message
+
+
+def test_jax104_good_span_syncs_inside():
+    src = """
+    import jax
+    from repro.common.spans import Span
+
+    f = jax.jit(lambda x: x * 2)
+
+    def step(x, stats):
+        with Span("engine.decode", stats, "decode_time"):
+            y = f(x)
+            y = jax.device_get(y)
+        with Span("engine.replay", stats, "replay_time"):
+            n = sum(int(v) for v in y)
+        return n
+    """
+    assert findings(src, "JAX104") == []
