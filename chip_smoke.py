@@ -135,8 +135,8 @@ def check_kernels(cfg, seed: int):
     # decode attention against a dense cache of per-row lengths
     B, L = 48, 320
     q = normal((B, 1, H, hd), bf16)
-    kc = normal((B, L, KV, hd), bf16)
-    vc = normal((B, L, KV, hd), bf16)
+    kc = normal((B, L, KV * hd), bf16)
+    vc = normal((B, L, KV * hd), bf16)
     lens = jax.random.randint(next(ks), (B,), 1, L + 1)
     got = da_ops.decode_attention(q, kc, vc, lens, interpret=False)
     with jax.default_matmul_precision("highest"):

@@ -674,9 +674,10 @@ def analyze_captured(call: CapturedCall, *,
 def _harness_decode_attn():
     import jax.numpy as jnp
     from repro.kernels.decode_attn.decode_attn import decode_attention_kernel
-    B, H, KV, hd, L = 2, 8, 2, 128, 2048
+    # L off the 512 block: the ragged last block and the live-block clamp
+    B, H, KV, hd, L = 2, 8, 2, 128, 2496
     q = jnp.zeros((B, H, hd), jnp.bfloat16)
-    k = jnp.zeros((B, KV, L, hd), jnp.bfloat16)
+    k = jnp.zeros((B, L, KV * hd), jnp.bfloat16)
     cl = jnp.array([L, L // 2], jnp.int32)
     decode_attention_kernel(q, k, k, cl, block_l=512)
 

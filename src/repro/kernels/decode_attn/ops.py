@@ -4,7 +4,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.decode_attn.decode_attn import decode_attention_kernel
 
@@ -14,11 +13,12 @@ from repro.kernels.decode_attn.decode_attn import decode_attention_kernel
 def decode_attention(q, k_cache, v_cache, cache_len, *, window=0,
                      attn_softcap=0.0, scale=0.0, block_l=512,
                      interpret=None):
-    """Model layout: q (B, 1, H, hd); caches (B, L, KV, hd); cache_len (B,).
+    """Model layout: q (B, 1, H, hd); caches (B, L, KV*hd) as the dense
+    cache stores them, read in place; cache_len (B,).
     Returns (B, 1, H, hd) — drop-in for models.attention.decode_attention."""
     interp = (jax.default_backend() == "cpu") if interpret is None else interpret
     out = decode_attention_kernel(
-        q[:, 0], k_cache.transpose(0, 2, 1, 3), v_cache.transpose(0, 2, 1, 3),
-        cache_len, window=window, attn_softcap=attn_softcap, scale=scale,
-        block_l=block_l, interpret=interp)
+        q[:, 0], k_cache, v_cache, cache_len, window=window,
+        attn_softcap=attn_softcap, scale=scale, block_l=block_l,
+        interpret=interp)
     return out[:, None]

@@ -2,7 +2,7 @@
 
 The paged KV backend stores the cache as physical page pools indexed by a
 per-sequence block table, so the decode hot loop can no longer stream a
-contiguous (B, KV, L, hd) cache: each length block lives at a
+contiguous (B, L, KV*hd) cache: each length block lives at a
 runtime-computed page. The block table and sequence lengths are passed as
 scalar-prefetch operands (``PrefetchScalarGridSpec``) so the BlockSpec
 index maps can compute the page-indexed DMA source *before* the kernel

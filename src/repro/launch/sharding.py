@@ -203,16 +203,19 @@ def cache_pspec(path, leaf, cfg: ModelConfig, mesh: Mesh, *,
 
     spec = [None] * nd
     if name in ("k", "v") and "kvg" in mesh.axis_names:
-        # GQA-grouped serve mesh: kv heads over "kvg", length over "model"
+        # GQA-grouped serve mesh: kv heads over "kvg", length over "model";
+        # whole heads per shard of the dense (KV*hd) or paged (KV) head axis
         spec[off + 0] = dp
         spec[off + 1] = "model"
-        spec[off + 2] = "kvg"
+        if cfg.num_kv_heads % mesh.shape["kvg"] == 0:
+            spec[off + 2] = "kvg"
     elif name in ("mk", "mv") and "kvg" in mesh.axis_names:
         spec[off + 0] = dp
         spec[off + 2] = "kvg"
         spec[off + 3] = "model"
     elif name in ("k", "v"):
-        # (R?, B, L, KV, hd)
+        # dense (R?, B, L, KV*hd): "model" on the merged axis holds whole
+        # heads when it divides KV; paged pools are (R?, NP, ps, KV, hd)
         if cfg.num_kv_heads % tp_size == 0:
             if not shard_seq:
                 spec[off + 0] = dp

@@ -275,13 +275,16 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
                      scale: float = 0.0):
     """Single-token decode attention against a cache.
 
-    q: (B, 1, H, hd); k_cache/v_cache: (B, L, KV, hd); cache_len: (B,) —
-    number of valid cache entries *including* the current token's K/V (the
-    cache is updated before calling). Reference semantics for the
-    ``decode_attn`` Pallas kernel.
+    q: (B, 1, H, hd); k_cache/v_cache: (B, L, KV*hd) as the dense cache
+    stores them, or (B, L, KV, hd); cache_len: (B,) — number of valid cache
+    entries *including* the current token's K/V (the cache is updated before
+    calling). Reference semantics for the ``decode_attn`` Pallas kernel.
     """
     B, _, H, hd = q.shape
-    L, KV = k_cache.shape[1], k_cache.shape[2]
+    L = k_cache.shape[1]
+    k_cache = k_cache.reshape(B, L, -1, hd)
+    v_cache = v_cache.reshape(B, L, -1, hd)
+    KV = k_cache.shape[2]
     rep = H // KV
     if scale <= 0.0:
         scale = hd ** -0.5
@@ -364,7 +367,7 @@ def attention_block(params, cfg, x, positions, *, kind: str,
 
     Training/prefill: kv_cache is None -> returns (out, (k, v)) where k/v are
     the full-sequence keys/values (for cache seeding).
-    Decode: kv_cache=(k_cache, v_cache) pre-allocated (B, L, KV, hd),
+    Decode: kv_cache=(k_cache, v_cache) pre-allocated (B, L, KV*hd),
     cache_len (B,) = tokens already in cache; x is (B, 1, d). Returns
     (out, (k_cache', v_cache')) with the new token written at cache_len.
     Paged decode: ``paged=(block_table (B, max_pages) int32, page_size)`` and
@@ -417,19 +420,22 @@ def attention_block(params, cfg, x, positions, *, kind: str,
         k_cache, v_cache = kv_cache
         B = x.shape[0]
         idx = cache_len                                           # (B,)
+        # the dense cache keeps (KV, hd) merged: (B, L, KV*hd)
+        k_new = k.reshape(B, 1, kv * hd).astype(k_cache.dtype)
+        v_new = v.reshape(B, 1, kv * hd).astype(v_cache.dtype)
         if cfg.cache_update == "onehot":
             # select-based write: SPMD-shardable along the cache length dim
             # (dynamic_update_slice with per-row indices makes XLA gather an
             # L-sharded cache every layer — hillclimb B)
             hit = (jnp.arange(k_cache.shape[1])[None, :]
-                   == idx[:, None])[..., None, None]              # (B,L,1,1)
-            k_cache = jnp.where(hit, k.astype(k_cache.dtype), k_cache)
-            v_cache = jnp.where(hit, v.astype(v_cache.dtype), v_cache)
+                   == idx[:, None])[..., None]                    # (B,L,1)
+            k_cache = jnp.where(hit, k_new, k_cache)
+            v_cache = jnp.where(hit, v_new, v_cache)
         else:
             k_cache = jax.vmap(lambda c, t, i: jax.lax.dynamic_update_slice(
-                c, t, (i, 0, 0)))(k_cache, k.astype(k_cache.dtype), idx)
+                c, t, (i, 0)))(k_cache, k_new, idx)
             v_cache = jax.vmap(lambda c, t, i: jax.lax.dynamic_update_slice(
-                c, t, (i, 0, 0)))(v_cache, v.astype(v_cache.dtype), idx)
+                c, t, (i, 0)))(v_cache, v_new, idx)
         if use_pallas:
             from repro.kernels.decode_attn import ops as da_ops
             out = da_ops.decode_attention(q, k_cache, v_cache, cache_len + 1,

@@ -67,20 +67,22 @@ def init_block(key, cfg: ModelConfig, kind: str, dtype):
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                      dtype, *, kv_pages=None):
-    """``kv_pages=(num_pages, page_size)`` switches the attention K/V leaves
-    (dict keys "k"/"v") to a physical page pool (num_pages, page_size, kv,
-    hd) shared by all slots; every other leaf keeps its per-slot batch axis
-    (no length axis to page)."""
+    """Dense attention K/V leaves (dict keys "k"/"v") are (batch, max_len,
+    kv*hd): the heads merged into one minor dim, which the decode kernel
+    reads in place. ``kv_pages=(num_pages, page_size)`` switches them to a
+    physical page pool (num_pages, page_size, kv, hd) shared by all slots;
+    every other leaf keeps its per-slot batch axis (no length axis to
+    page)."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     if kv_pages is not None:
-        kv_shape = kv_pages
+        kv_shape = (*kv_pages, kv, hd)
     else:
-        kv_shape = (batch, max_len)
+        kv_shape = (batch, max_len, kv * hd)
     if kind in ("attn", "local", "global", "moe"):
         # sliding-window layers only ever read the last `window` entries but
         # we keep the full ring for simplicity of absolute indexing.
-        return {"k": jnp.zeros((*kv_shape, kv, hd), dtype),
-                "v": jnp.zeros((*kv_shape, kv, hd), dtype)}
+        return {"k": jnp.zeros(kv_shape, dtype),
+                "v": jnp.zeros(kv_shape, dtype)}
     if kind == "xattn":
         # media K/V are static per request: computed at prefill, reused at
         # every decode step (hillclimb C)
@@ -92,8 +94,8 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     if kind == "hymba":
         di = ssm_mod.d_inner_of(cfg)
         K = cfg.ssm.conv_dim
-        return {"k": jnp.zeros((*kv_shape, kv, hd), dtype),
-                "v": jnp.zeros((*kv_shape, kv, hd), dtype),
+        return {"k": jnp.zeros(kv_shape, dtype),
+                "v": jnp.zeros(kv_shape, dtype),
                 "ssm": jnp.zeros((batch, di, cfg.ssm.state_dim), jnp.float32),
                 "conv": jnp.zeros((batch, K - 1, di), dtype)}
     raise ValueError(kind)
@@ -102,6 +104,17 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
 # ---------------------------------------------------------------------------
 # per-layer apply
 # ---------------------------------------------------------------------------
+
+
+def _seed_kv(cache, k, v):
+    """Prefill write: k/v (B, S, KV, hd) into the first S positions of the
+    dense (B, L, KV*hd) cache leaves."""
+    B, S = k.shape[:2]
+
+    def put(c, t):
+        return jax.lax.dynamic_update_slice(
+            c, t.reshape(B, S, -1).astype(c.dtype), (0, 0, 0))
+    return put(cache["k"], k), put(cache["v"], v)
 
 
 def _gather_last(x, lengths):
@@ -137,11 +150,7 @@ def apply_block(params, cfg: ModelConfig, kind: str, x, *, positions,
                 params["attn"], cfg, h, positions, kind=kind,
                 use_pallas=use_pallas)
             if mode == "prefill":
-                S = x.shape[1]
-                kc = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+                kc, vc = _seed_kv(cache, k, v)
                 new_cache = dict(cache, k=kc, v=vc)
         x = x + a
         h2 = rms_norm(x, params["ln2"], eps=cfg.rms_eps)
@@ -229,10 +238,7 @@ def apply_block(params, cfg: ModelConfig, kind: str, x, *, positions,
                 params["ssm"], cfg, h, None, None, seq_mask=seq_mask,
                 lengths=lengths, use_pallas=use_pallas)
             if mode == "prefill":
-                kc = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+                kc, vc = _seed_kv(cache, k, v)
                 new_cache = dict(cache, k=kc, v=vc, ssm=ssm_st, conv=conv_st)
         fused = (params["beta"].astype(x.dtype)[0]
                  * rms_norm(a, params["fuse_norm_a"], eps=cfg.rms_eps)

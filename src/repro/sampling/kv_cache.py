@@ -152,9 +152,12 @@ def paged_insert_rows(cache, scratch, slot_ids, row_map, flat_pos):
     host computed it from the block table; unmapped/padding positions carry
     an out-of-bounds sentinel and are dropped. Per-slot leaves scatter by
     ``slot_ids`` after gathering ``row_map`` (so prefix-shared samples get
-    their own copy of the non-KV state)."""
+    their own copy of the non-KV state). The scratch is a dense cache:
+    its K/V rows (..., S, kv*hd) split into the pool's (kv, hd)."""
     def upd(axis, is_kv, big, small):
         if is_kv:
+            small = small.reshape(small.shape[:axis + 2]
+                                  + big.shape[axis + 2:])
             f = _flat(big, axis)
             if axis == 0:
                 f = f.at[flat_pos].set(small.astype(big.dtype), mode="drop")

@@ -1,6 +1,6 @@
 """The main-path Pallas kernels compile for a described v5e chip at
 llama3.2-1b widths (d_model 2048, 32 query / 8 KV heads, head_dim 64,
-vocab 128256).
+vocab 128256), and decode attention at the benchmark cell's widths too.
 
 Nothing runs: the TPU compiler, installed on the host, compiles for a chip
 that is described and not attached, and refuses what the chip would refuse
@@ -61,13 +61,17 @@ def test_flash_attn(one_chip):
              ((8, 256, KV, HD), BF16))
 
 
-def test_decode_attn(one_chip):
+@pytest.mark.parametrize("b,l,h,kv,hd", [
+    (48, 320, H, KV, HD),        # llama3.2-1b: 128-lane blocks of two heads
+    (64, 2496, 12, 2, 128),      # r1-distill-qwen-1.5b cell: ragged last block
+])
+def test_decode_attn(one_chip, b, l, h, kv, hd):
     from repro.kernels.decode_attn import ops
     _compile(one_chip,
              lambda q, k, v, n: ops.decode_attention(q, k, v, n,
                                                      interpret=False),
-             ((48, 1, H, HD), BF16), ((48, 320, KV, HD), BF16),
-             ((48, 320, KV, HD), BF16), ((48,), I32))
+             ((b, 1, h, hd), BF16), ((b, l, kv * hd), BF16),
+             ((b, l, kv * hd), BF16), ((b,), I32))
 
 
 def test_paged_decode_attn(one_chip):

@@ -85,8 +85,8 @@ def test_decode_attention(case):
     B, L, H, KV, hd, win, cap, dt = case
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, 1, H, hd), dt)
-    kc = jax.random.normal(ks[1], (B, L, KV, hd), dt)
-    vc = jax.random.normal(ks[2], (B, L, KV, hd), dt)
+    kc = jax.random.normal(ks[1], (B, L, KV * hd), dt)
+    vc = jax.random.normal(ks[2], (B, L, KV * hd), dt)
     cl = jnp.arange(B) * 37 % (L - 8) + 5
     ref = da_ref.decode_attention(q, kc, vc, cl, window=win, attn_softcap=cap)
     out = da_ops.decode_attention(q, kc, vc, cl, window=win, attn_softcap=cap,
@@ -102,12 +102,125 @@ def test_decode_attention(case):
 def test_decode_attention_hypothesis(B, L, hd, win):
     ks = jax.random.split(jax.random.PRNGKey(B * 1000 + L), 3)
     q = jax.random.normal(ks[0], (B, 1, 4, hd))
-    kc = jax.random.normal(ks[1], (B, L, 2, hd))
-    vc = jax.random.normal(ks[2], (B, L, 2, hd))
+    kc = jax.random.normal(ks[1], (B, L, 2 * hd))
+    vc = jax.random.normal(ks[2], (B, L, 2 * hd))
     cl = (jnp.arange(B) * 13) % (L - 2) + 2
     ref = da_ref.decode_attention(q, kc, vc, cl, window=win)
     out = da_ops.decode_attention(q, kc, vc, cl, window=win, block_l=32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+def _transposed_padded_decode_attention(q, kc, vc, cl, *, window, cap,
+                                        block_l):
+    """Oracle: the online-softmax kernel as it ran on a relaid-out copy of
+    the cache, (B*KV, L padded to block_l, hd), one grid row per (row, KV
+    head) and every length block fetched."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, _, H, hd = q.shape
+    L = kc.shape[1]
+    KV = kc.shape[2] // hd
+    rep, scale = H // KV, hd ** -0.5
+
+    def relayout(c):
+        c = c.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)
+        c = jnp.pad(c, ((0, 0), (0, 0), (0, (-L) % block_l), (0, 0)))
+        return c.reshape(B * KV, -1, hd)
+
+    kg, vg = relayout(kc), relayout(vc)
+    nl = kg.shape[1] // block_l
+
+    def kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr):
+        li = pl.program_id(2)
+
+        @pl.when(li == 0)
+        def _():
+            m_scr[...] = jnp.full_like(m_scr, -1e30)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        n = len_ref[pl.program_id(0)]
+        start = li * block_l
+        lo = n - window if window > 0 else 0
+        run = start < n
+        if window > 0:
+            run = jnp.logical_and(run, start + block_l > lo)
+
+        @pl.when(run)
+        def _():
+            qf = q_ref[0].astype(jnp.float32)
+            k = k_ref[0].astype(jnp.float32)
+            v = v_ref[0].astype(jnp.float32)
+            s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            if cap > 0.0:
+                s = jnp.tanh(s / cap) * cap
+            pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = pos < n
+            if window > 0:
+                mask &= pos >= lo
+            s = jnp.where(mask, s, -1e30)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_scr[...] = m_new
+
+        @pl.when(li == nl - 1)
+        def _():
+            o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                        ).astype(o_ref.dtype)
+
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(B * KV, 1, nl),
+        in_specs=[pl.BlockSpec((1, rep, hd), lambda b, _, li, ln: (b, 0, 0)),
+                  pl.BlockSpec((1, block_l, hd),
+                               lambda b, _, li, ln: (b, li, 0)),
+                  pl.BlockSpec((1, block_l, hd),
+                               lambda b, _, li, ln: (b, li, 0))],
+        out_specs=pl.BlockSpec((1, rep, hd), lambda b, _, li, ln: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((rep, 1), jnp.float32),
+                        pltpu.VMEM((rep, 1), jnp.float32),
+                        pltpu.VMEM((rep, hd), jnp.float32)])
+    out = pl.pallas_call(
+        kernel, grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct((B * KV, rep, hd), q.dtype),
+        interpret=True,
+    )(jnp.repeat(cl.astype(jnp.int32), KV), q[:, 0].reshape(B * KV, rep, hd),
+      kg, vg)
+    return out.reshape(B, 1, H, hd)
+
+
+DA_BIT_CASES = [
+    # B, L, H, KV, hd, window, softcap, dtype: L never a multiple of 64
+    (3, 200, 12, 2, 128, 0, 0.0, jnp.bfloat16),   # one head per block
+    (2, 150, 16, 8, 64, 0, 0.0, jnp.float32),     # 128-lane pairs of heads
+    (2, 200, 12, 2, 128, 48, 0.0, jnp.float32),   # window
+    (2, 100, 4, 2, 32, 0, 30.0, jnp.float32),     # whole row (tiny)
+    (2, 90, 5, 5, 64, 32, 0.0, jnp.bfloat16),     # whole row (hymba)
+]
+
+
+@pytest.mark.parametrize("case", DA_BIT_CASES)
+def test_decode_attention_bit_identical_to_relayout(case):
+    """Reading the cache in place (ragged last block masked, blocks past
+    the last live one not fetched) changes no bit of the output."""
+    B, L, H, KV, hd, win, cap, dt = case
+    ks = jax.random.split(jax.random.PRNGKey(L + hd), 3)
+    q = jax.random.normal(ks[0], (B, 1, H, hd), dt)
+    kc = jax.random.normal(ks[1], (B, L, KV * hd), dt)
+    vc = jax.random.normal(ks[2], (B, L, KV * hd), dt)
+    cl = jnp.array([L, 37, 65, 129][:B], jnp.int32)
+    want = _transposed_padded_decode_attention(q, kc, vc, cl, window=win,
+                                               cap=cap, block_l=64)
+    got = da_ops.decode_attention(q, kc, vc, cl, window=win,
+                                  attn_softcap=cap, block_l=64)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.common.config import TrainConfig
-from repro.configs import ASSIGNED_ARCHS, get_smoke_config
+from repro.configs import ASSIGNED_ARCHS, get_config, get_smoke_config
 from repro.core.copris import make_train_step
 from repro.models import model as M
 from repro.optim import adam
@@ -94,3 +94,37 @@ def test_decode_matches_full_forward(arch):
             if pos + 1 <= S:
                 np.testing.assert_allclose(lg[b], full[b, pos], atol=5e-3)
         cache_len = cache_len + 1
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (scan, jit, pallas) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_pallas_decode_reads_dense_cache_in_place():
+    """The decode-attention kernel reads the dense cache as stored: no
+    transpose or pad of a cache-sized array runs on the decode step."""
+    cfg = get_config("tiny")
+    B, L = 2, 256
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    cache = M.init_cache(cfg, B, L)
+    layer = cache["body"][0]["k"].shape[1:]
+    assert layer == (B, L, cfg.num_kv_heads * cfg.head_dim)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c, t, n: M.decode_step(p, cfg, t, c, n, use_pallas=True))(
+        params, cache, jnp.zeros((B,), jnp.int32),
+        jnp.array([3, 200], jnp.int32)).jaxpr
+    eqns = list(_eqns(jaxpr))
+    assert any(e.primitive.name == "pallas_call" for e in eqns)
+    relayouts = [(e.primitive.name, v.aval.shape) for e in eqns
+                 if e.primitive.name in ("transpose", "pad")
+                 for v in e.invars
+                 if L in getattr(v.aval, "shape", ())
+                 and np.prod(v.aval.shape) >= np.prod(layer)]
+    assert relayouts == []
