@@ -23,8 +23,8 @@ from pathlib import Path
 if __package__ in (None, ""):          # run as a script: bench/ is on the path
     sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench.run import (ROOT, build, checked_steps, compare,  # noqa: E402
-                       leaf_report, reference_run)
+from bench.run import (ROOT, behaviour_worst, build,  # noqa: E402
+                       checked_steps, compare, leaf_report, reference_run)
 
 
 def control_checks(b, chk, ref):
@@ -107,7 +107,9 @@ def one_seed(cell, seed, *, control, faults=False, root=ROOT):
     secs = time.perf_counter() - t
     rows = [dict(seed=seed, side="system", secs=secs,
                  **{k: v for k, (v, _) in compare(chk, ref, {}).items()},
-                 leaves=leaf_report(chk, ref, chk["names"]))]
+                 leaves=leaf_report(chk, ref, chk["names"]),
+                 behaviour=behaviour_worst(chk, ref,
+                                           b["traffic"]["eos_id"]))]
     if control:
         t = time.perf_counter()
         got = control_checks(b, chk, ref)

@@ -1,6 +1,7 @@
-"""Plain float32 reference of the dense decoder both configurations use,
-with the RL update the trainer applies (GRPO with cross-stage importance
-sampling, token-mean, global-norm clip, AdamW).
+"""Plain float32 reference of the ``qwen2`` family's dense decoder
+(``bench/families/qwen2.py``), with the RL update the trainer applies
+(GRPO with cross-stage importance sampling, token-mean, global-norm clip,
+AdamW).
 
 Written from the published description (Qwen2 / Qwen3 blocks: RMSNorm,
 rotary positions on the two halves of each head, grouped-query causal
@@ -162,10 +163,16 @@ def group_advantages(rewards, G):
             / (r.std(1, keepdims=True) + 1e-6)).reshape(-1).astype(np.float32)
 
 
-def block_rows(cfg: dict, T: int, budget: int = 1 << 30) -> int:
-    """Rows per block: attention scores of a block within ``budget`` bytes."""
+SCORES_BUDGET = 1 << 30   # bytes of a block's attention scores
+LOGITS_BUDGET = 3 << 30   # bytes of a block's float32 logits
+
+
+def block_rows(cfg: dict, T: int) -> int:
+    """Rows per block, a power of two: a block's attention scores within
+    ``SCORES_BUDGET`` and its float32 logits within ``LOGITS_BUDGET``."""
     per_row = cfg["num_attention_heads"] * T * T * 4
-    n = max(1, budget // per_row)
+    n = max(1, min(SCORES_BUDGET // per_row,
+                   LOGITS_BUDGET // (T * cfg["vocab_size"] * 4)))
     return 1 << (n.bit_length() - 1)
 
 

@@ -13,8 +13,10 @@ passed (the window closes at the end of the first step that ends after
 them). ``--trace 1`` traces the window's first steps and reports the
 per-layer metrics instead of the end-to-end ones.
 
-After the window the trainer is freed and the plain float32 reference
-(``bench/reference.py``) follows the three set-up steps; ``correct`` says
+Everything that depends on the model goes through the family module the
+configuration file names (``bench/families/<family>.py``). After the
+window the trainer is freed and the family's plain float32 reference
+follows the three set-up steps; ``correct`` says
 whether each compared number is within its limit
 (``bench/limits/<cell>.json``). The last stdout line is the result object.
 """
@@ -23,6 +25,7 @@ from __future__ import annotations
 import argparse
 import gc
 import json
+import math
 import shutil
 import sys
 import tempfile
@@ -36,6 +39,9 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 CHECK_STEPS = 3      # set-up steps the reference follows
 TRACE_STEPS = 2      # window steps inside the profiler trace
+STEP_LOG = ("step_time", "rollout_time", "update_time", "prefill_time",
+            "decode_time", "replay_time", "pack_time", "pg_loss",
+            "ratio_mean", "off_policy_frac", "mean_resp_len")
 
 
 def parse(argv):
@@ -76,7 +82,7 @@ class DecodeLog:
 
     def __init__(self, engine):
         import numpy as np
-        self.calls = []           # (traced, ctx_sum, slot_steps)
+        self.calls = []           # (traced, live lengths, D)
         self.traced = False
         inner = engine._decode_chunk_fn
         D = engine.ro.decode_chunk
@@ -84,22 +90,14 @@ class DecodeLog:
         def wrapped(params, cache, last_token, cache_len, active, *rest):
             live = np.array([t is not None for t in engine.slots], bool)
             lens = engine.cache_len[live].astype(np.float64)
-            n = int(live.sum())
-            # step d of the chunk attends cache_len + d + 1 keys
-            ctx = D * lens.sum() + n * D * (D + 1) / 2.0
-            self.calls.append((self.traced, ctx, n * D))
+            self.calls.append((self.traced, lens, D))
             return inner(params, cache, last_token, cache_len, active, *rest)
 
         engine._decode_chunk_fn = wrapped
 
-    def totals(self, traced_only: bool):
-        sel = [c for c in self.calls if c[0] or not traced_only]
-        return (sum(c[1] for c in sel), sum(c[2] for c in sel))
-
 
 def build(cell_name, seed, *, root=ROOT, spans=False):
     """Configuration, traffic and trainer of a cell, weights from ``seed``."""
-    import jax
     import jax.numpy as jnp
     from bench import spec, weights
     from bench.traffic import TrafficTask
@@ -109,6 +107,7 @@ def build(cell_name, seed, *, root=ROOT, spans=False):
     bench = spec.load_benchmark(root)
     cell = spec.cell(bench, cell_name)
     cfg = spec.load_config(bench, cell["config"], root)
+    fam = spec.family(cfg, root / "bench")
     tfc = spec.load_traffic(cell["traffic"], root / "bench")
     kw = jnp.asarray(weights.np_key(seed))
     # the sampling stream is the traffic's, not the seed's: with the stop
@@ -131,12 +130,12 @@ def build(cell_name, seed, *, root=ROOT, spans=False):
         betas=tuple(tfc["betas"]), eps=tfc["adam_eps"],
         grad_clip=tfc["grad_clip"], seed=seed % (1 << 31),
         microbatches=cfg.get("train_microbatches", 1))
-    params = weights.make_params(cfg, tfc, kw)
-    tr = CoPRISTrainer(spec.model_config(cfg, cell["config"]), ro, tc, task,
+    params = fam.make_params(cfg, tfc, kw)
+    tr = CoPRISTrainer(fam.model_config(cfg, cell["config"]), ro, tc, task,
                        eos_id=tfc["eos_id"], key=kt, params=params)
     del params
-    return dict(bench=bench, cell=cell, cfg=cfg, traffic=tfc, task=task,
-                trainer=tr, key_weights=kw)
+    return dict(bench=bench, cell=cell, cfg=cfg, family=fam, traffic=tfc,
+                task=task, trainer=tr, key_weights=kw)
 
 
 def _batch_of(tr):
@@ -161,7 +160,6 @@ def checked_steps(b):
     and the params' change over the steps."""
     import jax
     import jax.numpy as jnp
-    from bench import weights
     tr = b["trainer"]
     outs, batches, g0 = [], [], None
     for k in range(CHECK_STEPS):
@@ -170,7 +168,7 @@ def checked_steps(b):
         if k == 0:
             g0 = _leaf_norms(tr.opt_state["m"],
                              1.0 / (1.0 - b["traffic"]["betas"][0]))
-    p0 = weights.make_params(b["cfg"], b["traffic"], b["key_weights"])
+    p0 = b["family"].make_params(b["cfg"], b["traffic"], b["key_weights"])
     delta = _leaf_norms(jax.tree.map(jnp.subtract, tr.params, p0))
     del p0
     return dict(losses=[o["pg_loss"] for o in outs], batches=batches,
@@ -183,9 +181,10 @@ def warm_shapes(b):
     """Run every program shape the window can meet that the first steps
     may not have: the batched prefill for fresh prompts at each power-of-two
     row count, the whole-pool prefill at each length bucket (the stage-start
-    refill of resumed partials), and the train step at the longest batch.
-    Prefill rows are aimed at an out-of-range slot, so the cache is left as
-    it was."""
+    refill of resumed partials), and the train step at each length a batch
+    is padded to (``train_lens``). Prefill rows are aimed at an
+    out-of-range slot, so the cache is left as it was."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.core.rollout import PREFILL_BUCKET, prefill_pad_dims
@@ -205,13 +204,9 @@ def warm_shapes(b):
             i32(np.full(nr, S)), i32(np.full(ns, eng.pool)),
             i32(np.zeros(ns)), i32(np.zeros((1, 1))), i32(np.zeros(ns)),
             i32(np.zeros(ns)), i32(np.zeros(ns)), key)
-    # a batch is padded to its longest trajectory: one holding a truncated
-    # response is max_len long; without one, the longest of its 32
-    # geometric responses is almost always within 256 tokens of the cap
     N = tf["batch_size"] * tf["group_size"]
-    train_T = range(max(PREFILL_BUCKET, eng.max_len - 4 * PREFILL_BUCKET),
-                    eng.max_len + 1, PREFILL_BUCKET)
-    import jax
+    p_stop = b["family"].design(b["cfg"], tf)["stop_prob_effective"]
+    train_T = train_lens(tf, p_stop, eng.max_len, PREFILL_BUCKET)
     jax.block_until_ready((tok, logp, eng.cache))
     for T in train_T:
         fake = dict(tokens=np.zeros((N, T), np.int32),
@@ -223,6 +218,23 @@ def warm_shapes(b):
         # time: the chip holds no two
         jax.block_until_ready(tr._train_step(tr.params, tr.opt_state, jb, lr))
     return len(shapes) + len(train_T)
+
+
+RARE_BATCH = 1 / 400  # a train length only rarer batches are padded to
+
+
+def train_lens(tf, stop_prob, max_len, bucket):
+    """The lengths a train batch is padded to that the warm-up runs. A batch
+    is padded to its longest trajectory, rounded up to ``bucket``; its N
+    responses are geometric (per-token stop ``stop_prob``) up to the cap,
+    so the longest is under r tokens with probability (1 - (1-p)^r)^N.
+    Lengths only a batch rarer than ``RARE_BATCH`` is padded to (taking
+    the shortest prompt) are left out."""
+    N = tf["batch_size"] * tf["group_size"]
+    r = math.log1p(-RARE_BATCH ** (1.0 / N)) / math.log1p(-stop_prob)
+    lo = tf["prompt_len_min"] + min(tf["max_response_len"], int(r))
+    lo = -(-lo // bucket) * bucket
+    return range(max(bucket, min(lo, max_len)), max_len + 1, bucket)
 
 
 def lengths_of(batches, cap, eos_id):
@@ -281,6 +293,61 @@ def compare(chk, ref, limits, beta_rule=1e-3):
     return {k: (v, limits.get(k)) for k, v in vals.items()}
 
 
+def behaviour_worst(chk, ref, eos_id):
+    """Where the widest behaviour log-prob gaps lie, for the log: the three
+    widest tokens, each with its step, row, position, prompt and total
+    length, response index, stage, whether a prefill produced it (the
+    first token of a response or of a stage: a re-prefill's) and the
+    tokens decoded since that prefill's, whether it is the stop token, and
+    both log-probs; and how many tokens, produced by prefill or by decode,
+    read over 0.015."""
+    import numpy as np
+    n, over = 3, 0.015
+    n_over = {"prefill": 0, "decode": 0}
+    top = []
+    for k, (bt, rl) in enumerate(zip(chk["batches"], ref["ref_logp"])):
+        sel = (bt["loss_mask"][:, 1:] > 0) & np.isfinite(rl)
+        gap = np.where(sel, np.abs(bt["behaviour_logp"][:, 1:] - rl), -1.0)
+        st = bt["stage_ids"]
+        t = np.arange(1, st.shape[1])
+        resp = t[None, :] - bt["prompt_lens"][:, None]
+        pre = (resp == 0) | (st[:, 1:] != st[:, :-1])
+        n_over["prefill"] += int((sel & pre & (gap > over)).sum())
+        n_over["decode"] += int((sel & ~pre & (gap > over)).sum())
+        for i in np.argsort(-gap, axis=None)[:n]:
+            r, j = np.unravel_index(i, gap.shape)
+            if gap[r, j] >= 0:
+                top.append((float(gap[r, j]), k, int(r), int(j) + 1,
+                            int(resp[r, j]), bool(pre[r, j])))
+    out = []
+    for g, k, r, t, rs, p in sorted(top, reverse=True)[:n]:
+        bt = chk["batches"][k]
+        st, u = bt["stage_ids"][r], t
+        while u - 1 >= bt["prompt_lens"][r] and st[u - 1] == st[t]:
+            u -= 1
+        out.append(dict(gap=g, step=k, row=r, pos=t, since_prefill=t - u,
+                        prompt=int(bt["prompt_lens"][r]),
+                        total=int(bt["total_lens"][r]), resp=rs,
+                        stage=int(bt["stage_ids"][r, t]), prefill=p,
+                        stop=bool(bt["tokens"][r, t] == eos_id),
+                        system=float(bt["behaviour_logp"][r, t]),
+                        reference=float(ref["ref_logp"][k][r, t - 1])))
+    return dict(worst=out, over=over, n_over=n_over)
+
+
+def judge(checks, limits):
+    """Whether every compared number is within its limit, and the numbers
+    compared. A number that the cell's limits file lists with a null limit
+    (no reading of the control or of a fault stands far enough above the
+    system's to set one from) is not compared; one the file leaves out
+    fails."""
+    compared = {k: c for k, c in checks.items()
+                if k not in limits or limits[k] is not None}
+    ok = all(lim is not None and v == v and v <= lim
+             for v, lim in compared.values())
+    return ok, compared
+
+
 def leaf_report(chk, ref, names, n=3):
     """The leaves that set the gradient and update gaps, for the log."""
     import numpy as np
@@ -300,15 +367,14 @@ def leaf_names(params):
 
 
 def reference_run(b, chk, precision="highest"):
-    """The reference over the set-up steps' batches, from the seed."""
-    from bench import reference, weights
-    tf = b["traffic"]
-    make = lambda: weights.make_params(b["cfg"], tf, b["key_weights"])
+    """The family's reference over the set-up steps' batches, from the
+    seed."""
+    tf, fam = b["traffic"], b["family"]
+    make = lambda: fam.make_params(b["cfg"], tf, b["key_weights"])
     hyper = dict(tf, betas=tuple(tf["betas"]),
                  microbatches=b["cfg"].get("train_microbatches", 1))
-    out = reference.run(b["cfg"], hyper, make(), chk["batches"],
-                        b["task"].reward_of, precision=precision,
-                        make_params=make)
+    out = fam.reference_run(b["cfg"], hyper, make(), chk["batches"],
+                            b["task"].reward_of, precision, make)
     out.pop("params")
     return out
 
@@ -363,6 +429,9 @@ def window(b, seconds, traced, trace_dir, counter, dlog):
 
 
 def end_to_end(w, setup_s):
+    """The end-to-end metrics by name. A metric ``<name>.<suffix>`` in
+    ``BENCHMARK.json`` reports ``<name>`` under a bound of its own, in the
+    cells its ``workloads`` lists."""
     import numpy as np
     toks = sum(float(np.sum(bt["loss_mask"])) for bt in w["batches"])
     return {"trained_tokens_per_s": toks / w["window_s"],
@@ -413,7 +482,7 @@ def main(argv=None, *, require_tpu=True, root=ROOT, hooks=None) -> int:
     L = lengths_of(w["batches"], tf["max_response_len"], tf["eos_id"])
     from bench import weights
     tgt = weights.expected_lengths(
-        weights.design(b["cfg"], tf)["stop_prob_effective"],
+        b["family"].design(b["cfg"], tf)["stop_prob_effective"],
         tf["max_response_len"])
     log(f"bench: window {w['window_s']:.3f}s (trace writing "
         f"{w['trace_stop_s']:.3f}s), {len(w['steps'])} steps, "
@@ -422,10 +491,7 @@ def main(argv=None, *, require_tpu=True, root=ROOT, hooks=None) -> int:
     log("bench: response lengths trained in the window: " + json.dumps(L)
         + " target " + json.dumps(tgt))
     log("bench: steps " + json.dumps([
-        {k: round(s[k], 4) for k in ("step_time", "rollout_time",
-                                     "update_time", "pg_loss", "ratio_mean",
-                                     "off_policy_frac", "mean_resp_len")}
-        for s in w["steps"]]))
+        {k: round(s[k], 4) for k in STEP_LOG if k in s} for s in w["steps"]]))
     stats = [d.memory_stats() or {} for d in devs[:cell["chips"]]]
     peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
 
@@ -441,11 +507,11 @@ def main(argv=None, *, require_tpu=True, root=ROOT, hooks=None) -> int:
         result["device"]["busy_s"] = red.busy_s
         result["device"]["window_s"] = red.window_s
         result["breakdown"] = red.breakdown()
-        ctx = dict(cfg=b["cfg"], traffic=tf, w=w, trace=red,
+        ctx = dict(cfg=b["cfg"], family=b["family"], traffic=tf, w=w,
+                   trace=red,
                    peaks=roofline.peaks(devs[0].device_kind)
                    if require_tpu else roofline.PEAKS["TPU v5 lite"],
-                   chips=cell["chips"], decode=dlog.totals(False),
-                   decode_traced=dlog.totals(True))
+                   chips=cell["chips"], decode_calls=dlog.calls)
         for m in spec.metrics_for(bench, args.workload, "per_layer"):
             v = spec.load_reader(m["name"], root / "bench")(ctx)
             if v is not None:
@@ -456,8 +522,8 @@ def main(argv=None, *, require_tpu=True, root=ROOT, hooks=None) -> int:
     else:
         e2e = end_to_end(w, setup_s)
         for m in spec.metrics_for(bench, args.workload, "end_to_end"):
-            result["metrics"][m["name"]] = {"value": e2e[m["name"]],
-                                            "unit": m["unit"]}
+            v = e2e[m["name"].split(".")[0]]
+            result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
 
     # free the trainer before the reference runs on the same chips
     tr.close()
@@ -470,13 +536,16 @@ def main(argv=None, *, require_tpu=True, root=ROOT, hooks=None) -> int:
     checks = compare(chk, ref, limits)
     log("bench: worst leaves " + json.dumps(leaf_report(chk, ref,
                                                         chk["names"])))
-    ok = all(lim is not None and v == v and v <= lim
-             for v, lim in checks.values())
+    log("bench: behaviour worst " + json.dumps(
+        behaviour_worst(chk, ref, tf["eos_id"])))
+    ok, compared = judge(checks, limits)
+    log("bench: not compared " + json.dumps(
+        {k: v for k, (v, _) in checks.items() if k not in compared}))
     log(f"bench: reference in {time.perf_counter() - t:.3f}s")
     result["correct"] = bool(ok)
     result["checks"] = {k: {"value": v, "limit": lim}
-                        for k, (v, lim) in checks.items()}
-    for k, (v, lim) in checks.items():
+                        for k, (v, lim) in compared.items()}
+    for k, (v, lim) in compared.items():
         print(f"check {k} {v!r} limit {lim!r}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0

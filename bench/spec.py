@@ -4,7 +4,10 @@ A cell (``workloads`` entry) names a configuration and a traffic mix; each
 is one JSON file found by name (``bench/configs/<config>.json``,
 ``bench/traffic/<traffic>.json``), its correctness limits are
 ``bench/limits/<cell>.json``, and each per-layer metric is a reader
-``bench/metrics/<metric>.py``. Adding any of them is adding files.
+``bench/metrics/<metric>.py``. A configuration file names its family
+(``"family"``), a module ``bench/families/<family>.py`` through which
+everything that depends on the model goes. Adding any of them is adding
+files.
 """
 from __future__ import annotations
 
@@ -44,8 +47,8 @@ def load_traffic(name: str, bench_dir: Path = BENCH) -> dict:
 
 
 def load_limits(cell_name: str, bench_dir: Path = BENCH) -> dict:
-    """Each compared number's limit; none (so nothing passes) for a cell
-    whose limits have not been set."""
+    """Each compared number's limit (None: not compared); none (so nothing
+    passes) for a cell whose limits have not been set."""
     path = bench_dir / "limits" / f"{cell_name}.json"
     if not path.exists():
         return {}
@@ -59,27 +62,38 @@ def metrics_for(bench: dict, cell_name: str, kind: str) -> list:
             if "workloads" not in m or cell_name in m["workloads"]]
 
 
+def _load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(metric_name: str, bench_dir: Path = BENCH):
     """The ``read(ctx)`` function of ``bench/metrics/<metric_name>.py``."""
     path = bench_dir / "metrics" / f"{metric_name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric_name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(
+        path, "bench_metric_" + metric_name.replace(".", "_")).read
 
 
-def model_config(cfg: dict, name: str):
-    """The system's ``ModelConfig`` for a configuration file (HF keys)."""
-    from repro.common.config import ModelConfig
-    return ModelConfig(
-        name=name, family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        block_pattern=("attn",), qk_norm=cfg["qk_norm"],
-        rope_theta=float(cfg["rope_theta"]), rms_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        dtype=cfg["torch_dtype"], param_dtype=cfg["param_dtype"],
-        source=cfg["source"])
+_FAMILIES: dict = {}
+
+
+def load_family(family_name: str, bench_dir: Path = BENCH):
+    """The module ``bench/families/<family_name>.py``, loaded once per
+    file, so its jitted functions keep their caches across calls."""
+    path = (bench_dir / "families" / f"{family_name}.py").resolve()
+    if path not in _FAMILIES:
+        _FAMILIES[path] = _load_module(path, "bench_family_" + family_name)
+    return _FAMILIES[path]
+
+
+def family(cfg: dict, bench_dir: Path = BENCH):
+    """The family module a configuration file names."""
+    return load_family(cfg["family"], bench_dir)
+
+
+def model_config(cfg: dict, name: str, bench_dir: Path = BENCH):
+    """The system's ``ModelConfig`` for a configuration file, from its
+    family."""
+    return family(cfg, bench_dir).model_config(cfg, name)

@@ -1,4 +1,5 @@
-"""Seeded random weights with a shaped stop token.
+"""Seeded random weights with a shaped stop token, for the ``qwen2``
+family (``bench/families/qwen2.py``).
 
 With random weights no response would ever stop, so the benchmark imposes
 the response-length distribution through the weights themselves. Two

@@ -9,7 +9,7 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parents[2] / "bench"
 
 TINY_CFG = {
-    "source": "tiny CPU stand-in", "model_type": "qwen3",
+    "source": "tiny CPU stand-in", "model_type": "qwen3", "family": "qwen2",
     "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -33,12 +33,15 @@ def make_root(tmp: Path, *, cfg=None, traffic=None, limits=None,
               cells=(("tiny.mix", "tiny", "mix"),)) -> Path:
     """A root holding BENCHMARK.json (the real one's metrics, with the
     given cells) and a ``bench`` directory whose configs, traffic and
-    limits are the tiny ones; metric readers are the real files."""
+    limits are the tiny ones; metric readers are the real files, and so
+    are the family modules (a test may add its own beside them)."""
     real = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     b = tmp / "bench"
-    for d in ("configs", "traffic", "limits"):
+    for d in ("configs", "traffic", "limits", "families"):
         (b / d).mkdir(parents=True, exist_ok=True)
     os.symlink(BENCH / "metrics", b / "metrics")
+    for f in (BENCH / "families").glob("*.py"):
+        os.symlink(f, b / "families" / f.name)
     cfgs = {}
     for name, c, t in cells:
         cfgs[c] = {"name": c, "source": "tiny", "file": f"bench/configs/{c}.json",

@@ -71,3 +71,26 @@ def test_fault_reads_incorrect(fault, tmp_path, capsys):
     assert rc == 0
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert res["correct"] is (fault == "sound"), res["checks"]
+
+
+def test_null_limit_is_not_compared():
+    """A number whose limit the cell's file gives as null is reported
+    apart and not judged; a number the file leaves out, or one over its
+    limit, reads incorrect."""
+    checks = {"loss_gap": (3e-6, None), "grad_gap": (0.01, 0.1)}
+    ok, compared = run.judge(checks, {"loss_gap": None, "grad_gap": 0.1})
+    assert ok and set(compared) == {"grad_gap"}
+    assert not run.judge({"grad_gap": (0.01, None)}, {})[0]
+    assert not run.judge({"grad_gap": (0.2, 0.1)}, {"grad_gap": 0.1})[0]
+    assert not run.judge({"grad_gap": (float("nan"), 0.1)},
+                         {"grad_gap": 0.1})[0]
+
+
+def test_short_cell_limits():
+    """The short cell compares gradient, update and behaviour, each
+    limit between its system and its control or fault reading."""
+    lim = json.loads((spec.BENCH / "limits" / "r1q1.5b.short.json")
+                     .read_text())
+    assert lim["loss_gap"]["limit"] is None
+    for k in ("grad_gap", "update_gap", "behaviour_gap"):
+        assert lim[k]["lower"] < lim[k]["limit"] < lim[k]["upper"], k

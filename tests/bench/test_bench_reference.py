@@ -16,9 +16,10 @@ def test_reference_matches_model(tied, qk_norm):
     from repro.models import model as M
     cfg = dict(TINY_CFG, tie_word_embeddings=tied, qk_norm=qk_norm,
                torch_dtype="float32")
-    mcfg = spec.model_config(cfg, "tiny")
-    params = weights.make_params(cfg, TINY_TRAFFIC,
-                                 jnp.asarray(weights.np_key(5)))
+    fam = spec.family(cfg)
+    mcfg = fam.model_config(cfg, "tiny")
+    params = fam.make_params(cfg, TINY_TRAFFIC,
+                             jnp.asarray(weights.np_key(5)))
     rng = np.random.default_rng(3)
     N, T = 4, 40
     toks = rng.integers(2, cfg["vocab_size"], (N, T)).astype(np.int32)
@@ -57,6 +58,21 @@ def test_reference_matches_model(tied, qk_norm):
     for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_sys)):
         assert float(jnp.linalg.norm(a - b)) <= 2e-3 * float(
             jnp.linalg.norm(b)) + 1e-7
+
+
+def test_block_rows():
+    """A block's attention scores within 1 GiB and its float32 logits
+    within 3 GiB: at every train length of the longtail from 1728 up the
+    rows the scores alone gave (so its reference is unchanged), 8 at the
+    short mix's 704, where the scores alone would allow 32 rows and 6.8 GB
+    of logits."""
+    cfg = spec.load_config(spec.load_benchmark(), "r1-distill-qwen-1.5b")
+    for T in range(1728, 2497, 64):
+        n = (1 << 30) // (cfg["num_attention_heads"] * T * T * 4)
+        assert reference.block_rows(cfg, T) == 1 << (n.bit_length() - 1), T
+    assert reference.block_rows(cfg, 2496) == 2
+    assert reference.block_rows(cfg, 704) == 8
+    assert reference.block_rows(TINY_CFG, 40) == 1 << 15
 
 
 def test_group_advantages():
